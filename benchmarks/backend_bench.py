@@ -3,7 +3,7 @@
 The tentpole claim of DESIGN.md §10 measured: the same bucketed Program
 waves executed by the ``lax.scan`` reference interpreters and by the fused
 ``pl.pallas_call`` backend (interpret mode on CPU, native kernels on
-TPU/GPU), over the matmul / conv2d / elementwise traced builders from
+TPU), over the matmul / conv2d / elementwise traced builders from
 ``benchmarks/scaling.py`` and the tiles∈{1..16} scaling sweep.
 
 Two numbers per (kernel, tiles, backend) configuration:
@@ -167,6 +167,8 @@ if __name__ == "__main__":
         _root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         sys.path.insert(0, _root)
         sys.path.insert(0, os.path.join(_root, "src"))
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced CI gate (mul+matmul, tiles 1/4, asserts "

@@ -157,6 +157,8 @@ def main(path: str = "results/dryrun", out_csv: str | None = None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     import sys
     main(out_csv=sys.argv[1] if len(sys.argv) > 1 else
          "results/roofline.csv")

@@ -166,6 +166,8 @@ if __name__ == "__main__":
         _root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         sys.path.insert(0, _root)
         sys.path.insert(0, os.path.join(_root, "src"))
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced CI gate (users 1/4, tiles 4; asserts "

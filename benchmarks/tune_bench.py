@@ -163,6 +163,8 @@ def main(smoke: bool = False, sew: int = 8, bound: float = BOUND_PCT) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help=f"enforce the gate: matmul/conv2d tuned plans "

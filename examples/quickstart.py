@@ -156,7 +156,7 @@ def main():
     print("=" * 64)
     # same kernel, same runtime, different executor: the whole bucketed
     # instruction stream fuses into one pl.pallas_call (interpret mode on
-    # CPU, native kernels on TPU/GPU; backend='auto' picks per device)
+    # CPU, native kernels on TPU; backend='auto' picks per device)
     import time
 
     ref = np.asarray(matmul8(A, B, backend="scan"))
@@ -220,4 +220,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -3,12 +3,8 @@
 Set by the trainer / server / dry-run launcher; consulted by model code for
 sharding constraints and by the MoE layer for its shard_map.  When no mesh is
 active (unit tests, single-host experiments) everything degrades to plain
-single-device execution.
-
-Also home of the version-compat :func:`shard_map` wrapper (DESIGN.md §6):
-newer JAX exposes ``jax.shard_map(..., check_vma=...)``, JAX 0.4.x only has
-``jax.experimental.shard_map.shard_map(..., check_rep=...)`` — every
-shard_map in the repo goes through this one function.
+single-device execution.  Every shard_map in the repo goes through
+:func:`shard_map` here.
 """
 
 from __future__ import annotations
@@ -26,16 +22,9 @@ MODEL_AXIS = "model"
 
 
 def shard_map(fn, *, mesh: Mesh, in_specs, out_specs):
-    """Version-compat ``shard_map`` (replication checking disabled).
-
-    Newer JAX: ``jax.shard_map`` with ``check_vma``; JAX 0.4.x:
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking disabled."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def set_mesh(mesh: Optional[Mesh]) -> None:

@@ -8,7 +8,10 @@ are never materialized.  Required by the 32k/500k context shapes.
 Supports GQA (q-head -> kv-head via index_map), causal masking, sliding
 windows, and a q-position offset for chunked prefill.  Block shapes default
 to (128, head_dim) q-tiles x (512, head_dim) kv-tiles; VMEM per step ~
-bq*d + 2*bk*d + bq*bk floats << VMEM budget.
+bq*d + 2*bk*d + bq*bk floats << VMEM budget.  Any Sq / Skv runs: a short
+sequence shrinks its tile (q to a multiple of 16, kv to a multiple of 128),
+and the rest zero-pads to whole tiles, padded keys masked out and padded
+query rows dropped.
 """
 
 from __future__ import annotations
@@ -26,7 +29,7 @@ NEG_INF = -1e30
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             nk: int, bq: int, bk: int, scale: float, causal: bool,
-            window: int | None, q_offset: int):
+            window: int | None, q_offset: int, skv: int):
     kv_i = pl.program_id(2)
 
     @pl.when(kv_i == 0)
@@ -42,7 +45,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     qpos = (pl.program_id(1) * bq + jax.lax.broadcasted_iota(
         jnp.int32, (bq, bk), 0) + q_offset)
     kpos = kv_i * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = jnp.ones((bq, bk), jnp.bool_)
+    mask = kpos < skv                                 # padded keys
     if causal:
         mask &= kpos <= qpos
     if window is not None:
@@ -79,21 +82,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     _, hkv, skv, _ = k.shape
     assert hq % hkv == 0
     group = hq // hkv
-    bq, bk = min(bq, sq), min(bk, skv)
-    assert sq % bq == 0 and skv % bk == 0, (sq, bq, skv, bk)
-    grid = (b * hq, sq // bq, skv // bk)
+    bq, bk = min(bq, _round_up(sq, 16)), min(bk, _round_up(skv, 128))
+    sqp, skvp = _round_up(sq, bq), _round_up(skv, bk)
+    grid = (b * hq, sqp // bq, skvp // bk)
     scale = float(1.0 / np.sqrt(d))
 
-    qs = q.reshape(b * hq, sq, d)
-    ks = k.reshape(b * hkv, skv, d)
-    vs = v.reshape(b * hkv, skv, dv)
+    pad = ((0, 0), (0, sqp - sq), (0, 0))
+    qs = jnp.pad(q.reshape(b * hq, sq, d), pad)
+    pad = ((0, 0), (0, skvp - skv), (0, 0))
+    ks = jnp.pad(k.reshape(b * hkv, skv, d), pad)
+    vs = jnp.pad(v.reshape(b * hkv, skv, dv), pad)
 
     def kv_map(h, i, j):
         return (h // group, j, 0)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, nk=skv // bk, bq=bq, bk=bk, scale=scale,
-                          causal=causal, window=window, q_offset=q_offset),
+        functools.partial(_kernel, nk=skvp // bk, bq=bq, bk=bk, scale=scale,
+                          causal=causal, window=window, q_offset=q_offset,
+                          skv=skv),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
@@ -101,7 +107,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pl.BlockSpec((1, bk, dv), kv_map),
         ],
         out_specs=pl.BlockSpec((1, bq, dv), lambda h, i, j: (h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hq, sq, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b * hq, sqp, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -109,4 +115,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         interpret=interpret,
     )(qs, ks, vs)
-    return out.reshape(b, hq, sq, dv)
+    return out[:, :sq].reshape(b, hq, sq, dv)
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m

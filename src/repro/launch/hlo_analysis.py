@@ -26,15 +26,9 @@ from collections import defaultdict
 
 
 def normalize_cost_analysis(cost) -> dict:
-    """Version-compat view of ``compiled.cost_analysis()``.
+    """``compiled.cost_analysis()`` as a fresh dict (``{}`` for ``None``)."""
+    return dict(cost or {})
 
-    JAX 0.4.x returns a one-element *list* of dicts (one per computation);
-    newer JAX returns the dict directly.  Everything in the repo reads the
-    result through this helper so both shapes work (fields like ``"flops"``
-    and ``"bytes accessed"`` are then plain ``dict.get`` lookups)."""
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost) if cost else {}
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1,
                 "f8e5m2": 1, "s64": 8, "u64": 8, "s32": 4, "u32": 4,

@@ -2,13 +2,8 @@
 
 Defined as functions (never module-level constants) so importing this module
 never touches jax device state — required because the dry-run must set
-XLA_FLAGS before the first jax initialization.
-
-JAX version support: ``jax.sharding.AxisType`` only exists on newer JAX
-(>= 0.5); on 0.4.x meshes are built without ``axis_types`` (every axis is
-implicitly "auto", which is exactly what ``AxisType.Auto`` requests).
-:func:`make_mesh` is the single version-compat constructor — everything in
-the repo (and the subprocess test scripts) builds meshes through it.
+XLA_FLAGS before the first jax initialization.  Every mesh in the repo (and
+in the subprocess test scripts) is built through :func:`make_mesh`.
 """
 
 from __future__ import annotations
@@ -16,23 +11,13 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-import jax.sharding
-from jax.sharding import Mesh
-
-
-def _axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=(AxisType.Auto,) * n`` when the installed JAX has
-    AxisType, else nothing (0.4.x behavior is implicitly auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(devices, axes: tuple[str, ...]) -> Mesh:
-    """Version-compat Mesh constructor: all axes auto-sharded."""
-    devices = np.asarray(devices)
-    return Mesh(devices, axes, **_axis_types_kw(len(axes)))
+    """Mesh constructor: all axes auto-sharded."""
+    return Mesh(np.asarray(devices), axes,
+                axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -9,10 +9,8 @@ collective a dense TP FFN needs.  No all_to_all, no GSPMD-surprising gathers,
 deterministic HLO.  (A reduce-scatter + all2all variant is evaluated in the
 §Perf hillclimb.)
 
-Runs inside ``context.shard_map`` (the version-compat wrapper over
-``jax.shard_map`` / ``jax.experimental.shard_map``) when a mesh is active;
-degrades to a
-single-shard call otherwise (unit tests).  Capacity-dropped tokens fall back
+Runs inside ``context.shard_map`` (``jax.shard_map``) when a mesh is
+active; degrades to a single-shard call otherwise (unit tests).  Capacity-dropped tokens fall back
 to zero contribution from routed experts (shared experts still apply),
 standard top-k capacity semantics.
 """

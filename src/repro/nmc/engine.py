@@ -108,20 +108,19 @@ class CarusTile(_EngineBase):
 
 
 #: Execution backends implementing the Engine protocol.  "scan" is the
-#: ``lax.scan`` reference interpreter; "pallas" is the fused-kernel fast
-#: path (``repro.nmc.pallas_engine``), auto-falling back to interpret
-#: mode on CPU.
+#: ``lax.scan`` reference interpreter; "pallas" is the fused TPU kernel
+#: (``repro.nmc.pallas_engine``; interpret mode when no TPU is attached,
+#: which only the CPU tests ask for).
 BACKENDS = ("scan", "pallas")
 
 _DEFAULT_ENGINES: dict[tuple[str, str], Engine] = {}
 
 
 def resolve_backend(backend: str) -> str:
-    """Map ``"auto"`` to the fast path on accelerators, scan elsewhere."""
+    """Map ``"auto"`` to the Pallas kernels on a TPU, scan elsewhere."""
     if backend == "auto":
         import jax
-        return "pallas" if jax.default_backend() in ("tpu", "gpu") \
-            else "scan"
+        return "pallas" if jax.default_backend() == "tpu" else "scan"
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown backend {backend!r}: valid backends are "

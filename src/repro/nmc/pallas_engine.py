@@ -1,48 +1,52 @@
-"""Pallas fast-path engine backend (DESIGN.md §10).
+"""Pallas engine backend (DESIGN.md §10).
 
 The scan engines (:mod:`repro.core.caesar` / :mod:`repro.core.carus`)
 interpret one instruction per ``lax.scan`` step with a ``lax.switch`` over
-the opcode — bit-exact, but the throughput ceiling of every sweep in the
-repo.  This module is the third implementation of the
-:class:`repro.nmc.engine.Engine` protocol: it lowers a bucketed
-:class:`repro.nmc.program.Program` wave to a **single** ``pl.pallas_call``
-that keeps each tile's entire memory image resident in fast kernel memory
-(VMEM on TPU; the interpreter's buffer on CPU) for the whole instruction
-stream — the paper's near-memory thesis applied to the simulator itself:
-N instructions cost one memory round-trip, not N.
+the opcode.  This module is the second implementation of the
+:class:`repro.nmc.engine.Engine` protocol: it runs a bucketed
+:class:`repro.nmc.program.Program` wave as **one** ``pl.pallas_call`` that
+keeps each tile's state in on-chip memory for the whole instruction stream
+— the paper's near-memory thesis applied to the simulator itself.
 
-Lowering contract (the shape every kernel variant shares):
+Kernel layout (one shape for both engines):
 
-* **tile-batch dimension → Pallas grid.**  A wave of T same-bucket
-  programs runs as ``grid=(T,)``; block specs slice tile ``t``'s
-  instruction stream ``[1, n_instr]`` and its lane-decomposed memory image
-  ``[1, state_rows, n_elems]`` out of the batch.  Tiles are independent by
-  construction (the pool's vmap contract), so grid steps never
-  communicate.
-* **memory image → resident lanes ref.**  The int32 word image is
-  unpacked once per call into *native-dtype lanes* (int8/int16/int32 —
-  one dtype-specialized kernel per SEW) and packed back once at the end;
-  ``input_output_aliases`` makes the state ref in-place.  Native-dtype
-  arithmetic gives two's-complement wraparound at SEW for free, which is
-  exactly the per-step pack/unpack truncation of the scan engines.
-* **instruction stream → ``fori_loop`` over a branch-free step.**
-  Instructions stay *runtime data* (the bucketed compile cache keys on
-  shape, never on contents), so the kernel cannot specialize per opcode.
-  Instead of a ``lax.switch``, every step computes all candidate results,
-  stacks them ``[n_ops, n_elems]``, selects row ``op``, and performs one
-  conditional scatter — no branches, one dynamic write per instruction.
-* **SEW specialization.**  ``sew`` is a static argument of the kernel
-  factory; the :class:`repro.nmc.pool.BucketedPool` cache key
-  ``(engine, sew, instr-bucket, tile-bucket, backend)`` therefore maps
-  one-to-one onto compiled Pallas kernels.
-* **CPU fallback.**  ``interpret=True`` is selected automatically when no
-  TPU/GPU is attached, so the whole backend (and its differential tests)
-  runs everywhere; ``backend="auto"`` in the frontend picks Pallas only
-  on accelerators, where the fused kernel is the fast path.
+* **grid = (tiles, instruction chunks).**  Tiles are independent (the
+  pool's vmap contract) and run on the ``"parallel"`` axis.  The stream is
+  NOP-padded to whole chunks of :data:`CHUNK` instructions and cut on an
+  ``"arbitrary"`` axis;
+  the tile's state block keeps the same block index along it, so the state
+  stays resident across chunks, and the accumulators (Caesar MAC/DOT,
+  Carus VL) carry across chunks in SMEM scratch.
+* **instruction stream → SMEM.**  Every stream field is an int32 SMEM block
+  of one chunk (the tile axis squeezed).  The scalar core reads the fields
+  of instruction ``i`` as scalars; at the largest bucket the registry and
+  the resident block produce (16384) the stream never sits in SMEM whole.
+* **NM-Caesar state → SMEM words.**  The 8192-word memory image is an SMEM
+  block too (32 KiB of the 1 MiB): each bus micro-op reads two words and
+  writes one, all scalar.  SIMD lanes are unpacked from the words with
+  shifts, computed, and packed back — per-step truncation at SEW, exactly
+  like the scan engine's pack/unpack.
+* **NM-Carus state → VMEM element rows.**  The VRF is unpacked outside the
+  kernel to sign-extended int32 elements, one register per ``(8, 128)``
+  tile (1024 elements: the register at SEW 8; padded at SEW 16/32), so
+  every vector op is lane-dense.  Results truncate to SEW at writeback.
+  Slides are a dynamic flat roll (``pltpu.roll`` on lanes, then sublanes)
+  plus masks in place of a gather.
+* **opcode → select chain.**  Instructions stay *runtime data* (the
+  bucketed compile cache keys on shape, never on contents), so every step
+  computes each opcode's candidate and keeps one with a chain of selects
+  on ``op`` — no branches, one state write per instruction.
+* **SEW specialization.**  ``sew`` is static: the
+  :class:`repro.nmc.pool.BucketedPool` cache key ``(engine, sew,
+  instr-bucket, tile-bucket, backend)`` maps one-to-one onto kernels.
 
-Semantics are bit-exact vs the scan engines and the ``alu.*_np`` numpy
-oracles at SEW 8/16/32 — property-fuzzed in ``tests/test_differential.py``
-and conformance-tested per opcode in ``tests/test_engines.py``.
+The same kernels run in Pallas interpret mode when no TPU is attached — the
+CPU test path only (:func:`interpret_mode`); with a TPU attached they always
+compile natively.  Semantics are bit-exact vs the scan engines and the
+``alu.*_np`` numpy oracles at SEW 8/16/32: property-fuzzed in
+``tests/test_differential.py``, conformance-tested per opcode in
+``tests/test_engines.py``, and compiled for a v5e in
+``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -52,208 +56,300 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import alu, isa
 from repro.core.isa import CaesarOp, VOp
 from repro.nmc.engine import CaesarTile, CarusTile
+from repro.nmc.program import NOP_OP_ID
 
-JNP_DTYPES = {8: jnp.int8, 16: jnp.int16, 32: jnp.int32}
-JNP_UDTYPES = {8: jnp.uint8, 16: jnp.uint16, 32: jnp.uint32}
+#: Instructions per grid step: one SMEM block per stream field (Carus: 8
+#: fields x 4 KiB, double-buffered — 64 KiB of a v5e's 1 MiB SMEM).  XLA
+#: lays a 1-D int32 array of 1024 words or more out in 1024-word tiles, and
+#: Mosaic refuses a block that is smaller than its operand's tile, so no
+#: stream block is shorter than this.
+CHUNK = 1024
+#: One Carus register as an int32 element tile (1024 = VLMAX at SEW 8).
+ROWS, LANES = 8, 128
+INT_MIN = -(1 << 31)
+
+CAESAR_FIELDS = ("op", "dest", "src1", "src2")
+CARUS_FIELDS = ("op", "vd", "vs1", "vs2", "sval1", "sval2", "imm", "mode")
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
 
 
-def default_interpret() -> bool:
-    """Pallas interpret mode unless a real accelerator is attached."""
-    return jax.default_backend() not in ("tpu", "gpu")
+def interpret_mode() -> bool:
+    """Pallas interpret mode exactly when no TPU is attached (CPU tests);
+    a TPU always runs the compiled kernels."""
+    return jax.default_backend() != "tpu"
+
+
+def _trunc(x, sew: int):
+    """Sign-extend the low SEW bits of int32 values (= pack then unpack)."""
+    if sew == 32:
+        return x
+    s = 32 - sew
+    return (x << s) >> s
+
+
+def _sew_mask(sew: int) -> int:
+    return -1 if sew == 32 else (1 << sew) - 1
+
+
+def _flat_stream(arrays: dict, fields: tuple, nop: int
+                 ) -> tuple[list, int]:
+    """``[T, n]`` stream fields -> flat ``[T * n_pad]`` int32 columns and
+    ``n_pad``, the stream NOP-padded to whole chunks."""
+    cols = [jnp.asarray(arrays[f], jnp.int32) for f in fields]
+    n = cols[0].shape[-1]
+    n_pad = -(-n // CHUNK) * CHUNK
+    if n_pad != n:
+        cols = [jnp.pad(c, ((0, 0), (0, n_pad - n)),
+                        constant_values=nop if f == "op" else 0)
+                for f, c in zip(fields, cols)]
+    return [c.reshape(-1) for c in cols], n_pad
+
+
+def _stream_spec(n_instr: int) -> pl.BlockSpec:
+    """Tile ``t``'s chunk ``c`` of one flat stream field, in SMEM."""
+    per_tile = n_instr // CHUNK
+    return pl.BlockSpec((CHUNK,), lambda t, c: (t * per_tile + c,),
+                        memory_space=pltpu.SMEM)
 
 
 # ---------------------------------------------------------------------------
-# NM-Caesar kernel: flat word memory as [mem_words, L] native-dtype lanes
+# NM-Caesar: the flat word memory in SMEM, scalar micro-ops
 # ---------------------------------------------------------------------------
 
-def _caesar_kernel(op_ref, dest_ref, s1_ref, s2_ref, lanes_in_ref,
-                   lanes_out_ref, *, sew: int):
-    L = 32 // sew
-    dt, udt = JNP_DTYPES[sew], JNP_UDTYPES[sew]
-    n_ops = len(CaesarOp)
-    lanes_out_ref[...] = lanes_in_ref[...]
+_CAESAR_WRITES = tuple(sorted(int(o) for o in isa.CAESAR_STORE_OPS))
+
+
+def _caesar_kernel(op_ref, dest_ref, s1_ref, s2_ref, mem_in_ref,
+                   mem_ref, acc_ref, *, sew: int):
+    n_lanes = 32 // sew
+    mask = _sew_mask(sew)
+    top = mem_ref.shape[0] - 1
+
+    @pl.when(pl.program_id(1) == 0)
+    def _load():
+        def copy(w, carry):
+            mem_ref[w] = mem_in_ref[w]
+            return carry
+        jax.lax.fori_loop(0, top + 1, copy, 0)
+        acc_ref[0] = 0
+        acc_ref[1] = 0
+
+    def lanes(w):
+        return [_trunc(w >> (sew * k), sew) for k in range(n_lanes)]
+
+    def pack(ls):
+        word = ls[0] & mask
+        for k in range(1, n_lanes):
+            word = word | ((ls[k] & mask) << (sew * k))
+        return word
 
     def step(i, carry):
         mac, dot = carry
-        op = op_ref[0, i]
-        dest = dest_ref[0, i]
-        a = lanes_out_ref[0, s1_ref[0, i]]
-        b = lanes_out_ref[0, s2_ref[0, i]]
-        au, bu = a.astype(udt), b.astype(udt)
-        # RVV shift semantics: amount mod SEW ((x mod 2^SEW) mod SEW is
-        # the same because SEW is a power of two <= 2^SEW)
-        sh = bu % udt(sew)
-        rows = [a] * n_ops
-        rows[int(CaesarOp.AND)] = a & b
-        rows[int(CaesarOp.OR)] = a | b
-        rows[int(CaesarOp.XOR)] = a ^ b
-        rows[int(CaesarOp.ADD)] = a + b
-        rows[int(CaesarOp.SUB)] = a - b
-        rows[int(CaesarOp.MUL)] = a * b
-        rows[int(CaesarOp.SLL)] = (au << sh).astype(dt)
-        rows[int(CaesarOp.SLR)] = (au >> sh).astype(dt)
-        rows[int(CaesarOp.SRA)] = a >> sh.astype(dt)
-        rows[int(CaesarOp.MIN)] = jnp.minimum(a, b)
-        rows[int(CaesarOp.MAX)] = jnp.maximum(a, b)
-        # packed MAC accumulator (native lanes == per-step pack truncation)
-        prod = a * b
-        mac_new = jnp.where(op == int(CaesarOp.MAC_INIT), prod, mac + prod)
+        op = op_ref[i]
+        dest = dest_ref[i]
+        a = mem_ref[jnp.clip(s1_ref[i], 0, top)]
+        b = mem_ref[jnp.clip(s2_ref[i], 0, top)]
+        is_init = op == int(CaesarOp.MAC_INIT)
+        vals, macs, dsum = [], [], 0
+        for ak, bk, mk in zip(lanes(a), lanes(b), lanes(mac)):
+            prod = ak * bk
+            sh = bk & (sew - 1)          # RVV: shift amount mod SEW
+            m_new = jnp.where(is_init, 0, mk) + prod
+            v = m_new                    # MAC_STORE stores the new MAC
+            for o, r in ((CaesarOp.AND, ak & bk), (CaesarOp.OR, ak | bk),
+                         (CaesarOp.XOR, ak ^ bk), (CaesarOp.ADD, ak + bk),
+                         (CaesarOp.SUB, ak - bk), (CaesarOp.MUL, prod),
+                         (CaesarOp.SLL, ak << sh),
+                         (CaesarOp.SLR,
+                          jax.lax.shift_right_logical(ak & mask, sh)),
+                         (CaesarOp.SRA, ak >> sh),
+                         (CaesarOp.MIN, jnp.where(ak < bk, ak, bk)),
+                         (CaesarOp.MAX, jnp.where(ak > bk, ak, bk))):
+                v = jnp.where(op == int(o), r, v)
+            vals.append(v)
+            macs.append(m_new)
+            dsum = dsum + prod
         is_mac = (op >= int(CaesarOp.MAC_INIT)) & \
             (op <= int(CaesarOp.MAC_STORE))
-        mac = jnp.where(is_mac, mac_new, mac)
-        # 32-bit DOT accumulator: sum of sign-extended lane products
-        dsum = (a.astype(jnp.int32) * b.astype(jnp.int32)).sum()
-        dot_new = jnp.where(op == int(CaesarOp.DOT_INIT), dsum, dot + dsum)
+        mac = jnp.where(is_mac, pack(macs), mac)
         is_dot = (op >= int(CaesarOp.DOT_INIT)) & \
             (op <= int(CaesarOp.DOT_STORE))
+        dot_new = jnp.where(op == int(CaesarOp.DOT_INIT), 0, dot) + dsum
         dot = jnp.where(is_dot, dot_new, dot)
-        rows[int(CaesarOp.MAC_STORE)] = mac
-        # DOT_STORE writes the scalar as one packed word (= unpack(dot))
-        rows[int(CaesarOp.DOT_STORE)] = jnp.stack(
-            [(dot >> (k * sew)).astype(dt) for k in range(L)])
-        val = jnp.stack(rows)[op]
-        is_binop = (op <= int(CaesarOp.MUL)) | \
-            ((op >= int(CaesarOp.SLL)) & (op <= int(CaesarOp.MAX))) | \
-            (op == int(CaesarOp.SRA))
-        writes = is_binop | (op == int(CaesarOp.MAC_STORE)) | \
-            (op == int(CaesarOp.DOT_STORE))
-        cur = lanes_out_ref[0, dest]
-        lanes_out_ref[0, dest] = jnp.where(writes, val, cur)
+        val = jnp.where(op == int(CaesarOp.DOT_STORE), dot, pack(vals))
+        writes = (dest >= 0) & (dest <= top) & functools.reduce(
+            jnp.logical_or, [op == o for o in _CAESAR_WRITES])
+        d = jnp.clip(dest, 0, top)
+        mem_ref[d] = jnp.where(writes, val, mem_ref[d])
         return mac, dot
 
-    # zero carries without captured constant arrays (Pallas kernels must
-    # not close over traced constants): derive the MAC zeros from a read
-    mac0 = lanes_in_ref[0, 0] * 0
-    jax.lax.fori_loop(0, op_ref.shape[1], step, (mac0, jnp.int32(0)))
+    acc_ref[0], acc_ref[1] = jax.lax.fori_loop(
+        0, CHUNK, step, (acc_ref[0], acc_ref[1]))
 
 
 @functools.lru_cache(maxsize=None)
-def _caesar_call(sew: int, n_instr: int, n_tiles: int, mem_words: int,
+def _caesar_call(sew: int, n_tiles: int, n_instr: int, mem_words: int,
                  interpret: bool):
-    L = 32 // sew
-    ispec = pl.BlockSpec((1, n_instr), lambda t: (t, 0))
-    lspec = pl.BlockSpec((1, mem_words, L), lambda t: (t, 0, 0))
+    mspec = pl.BlockSpec((mem_words,), lambda t, c: (t,),
+                         memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(_caesar_kernel, sew=sew),
-        grid=(n_tiles,),
-        in_specs=[ispec, ispec, ispec, ispec, lspec],
-        out_specs=lspec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, mem_words, L),
-                                       JNP_DTYPES[sew]),
-        input_output_aliases={4: 0},
+        grid=(n_tiles, n_instr // CHUNK),
+        in_specs=[_stream_spec(n_instr)] * len(CAESAR_FIELDS) + [mspec],
+        out_specs=mspec,
+        out_shape=jax.ShapeDtypeStruct((n_tiles * mem_words,), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
+        input_output_aliases={len(CAESAR_FIELDS): 0},
+        compiler_params=_PARAMS,
         interpret=interpret)
 
 
+def caesar_wave(batch_state, arrays: dict, *, sew: int, interpret: bool):
+    """``[T, mem_words]`` memory images + ``[T, n]`` stream fields -> final
+    images: one ``pallas_call`` for the whole wave."""
+    cols, n_instr = _flat_stream(arrays, CAESAR_FIELDS, NOP_OP_ID["caesar"])
+    n_tiles, mem_words = batch_state.shape
+    call = _caesar_call(sew, n_tiles, n_instr, mem_words, interpret)
+    out = call(*cols, batch_state.astype(jnp.int32).reshape(-1))
+    return out.reshape(n_tiles, mem_words)
+
+
 # ---------------------------------------------------------------------------
-# NM-Carus kernel: VRF as [n_regs, n_elems] native-dtype element rows
+# NM-Carus: the VRF as int32 element tiles in VMEM, vector micro-ops
 # ---------------------------------------------------------------------------
+
+def _flat_roll(x, sh, lane):
+    """``out.flat[i] = x.flat[(i - sh) mod x.size]`` for an (R, 128)
+    tile and a dynamic ``sh`` in ``[0, x.size)``: a lane roll, then the
+    two sublane rolls its wrapped and unwrapped lanes need."""
+    rows = x.shape[0]
+    q, r = sh // LANES, sh % LANES
+    y = pltpu.roll(x, r, 1)
+    return jnp.where(lane >= r, pltpu.roll(y, q, 0),
+                     pltpu.roll(y, (q + 1) % rows, 0))
+
+
+def _splat_at(x, ids, idx: int):
+    """Broadcast element ``x.flat[idx]`` over the tile."""
+    m = jnp.where(ids == idx, x, INT_MIN)
+    m = jnp.max(jnp.max(m, axis=1, keepdims=True), axis=0, keepdims=True)
+    return jnp.broadcast_to(m, x.shape)
+
 
 def _carus_kernel(op_ref, vd_ref, vs1_ref, vs2_ref, sval1_ref, sval2_ref,
-                  imm_ref, mode_ref, ids_ref, elems_in_ref, elems_out_ref,
-                  *, sew: int, n_regs: int, vlmax: int):
-    dt, udt = JNP_DTYPES[sew], JNP_UDTYPES[sew]
-    n_elems = elems_in_ref.shape[2]
-    n_vops = len(isa.VOP_COMPACT)
+                  imm_ref, mode_ref, vrf_in_ref, vrf_ref, vl_ref, *,
+                  sew: int, n_elems: int):
+    n_regs = vrf_ref.shape[0]
+    mask = _sew_mask(sew)
     cid = isa.COMPACT_ID
-    elems_out_ref[...] = elems_in_ref[...]
-    ids = ids_ref[...]                      # iota passed as input (no
-                                            # captured constants in kernels)
+    size = ROWS * LANES
+
+    @pl.when(pl.program_id(1) == 0)
+    def _load():
+        vrf_ref[...] = vrf_in_ref[...]
+        vl_ref[0] = n_elems               # VL starts at VLMAX
+
+    shape = (ROWS, LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    ids = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANES + lane
 
     def step(i, vl):
-        op = op_ref[0, i]
-        sval1, sval2 = sval1_ref[0, i], sval2_ref[0, i]
-        imm, mode = imm_ref[0, i], mode_ref[0, i]
+        op, mode = op_ref[i], mode_ref[i]
+        sval1, sval2 = sval1_ref[i], sval2_ref[i]
         indirect = (mode & isa.MODE_INDIRECT) != 0
         slide1 = (mode & isa.MODE_SLIDE1) != 0
         opmode = mode & 0x3
         # indirect register addressing: indices from sval2's LSBytes
-        vd = jnp.where(indirect, (sval2 >> 16) & 0xFF, vd_ref[0, i]) % n_regs
-        vs2 = jnp.where(indirect, (sval2 >> 8) & 0xFF, vs2_ref[0, i]) % n_regs
-        vs1 = jnp.where(indirect, sval2 & 0xFF, vs1_ref[0, i]) % n_regs
-        dst = elems_out_ref[0, vd]
-        s2 = elems_out_ref[0, vs2]
-        s1r = elems_out_ref[0, vs1]
-        scalar_b = jnp.where(opmode == isa.MODE_VI, imm, sval1)     # int32
-        b = jnp.where(opmode == isa.MODE_VV, s1r, scalar_b.astype(dt))
-
-        rows = [dst] * n_vops
-        # wraparound-closed ops compute directly in the native dtype
-        rows[cid[VOp.VADD]] = s2 + b
-        rows[cid[VOp.VSUB]] = s2 - b
-        rows[cid[VOp.VMUL]] = s2 * b
-        rows[cid[VOp.VAND]] = s2 & b
-        rows[cid[VOp.VOR]] = s2 | b
-        rows[cid[VOp.VXOR]] = s2 ^ b
-        # signed min/max compare the *untruncated* vx/vi scalar (the scan
-        # engine's lanes are sign-extended int32; truncation happens at
-        # pack) — widen to int32, select, then truncate the winner
-        b32 = jnp.where(opmode == isa.MODE_VV, s1r.astype(jnp.int32),
-                        scalar_b)
-        a32 = s2.astype(jnp.int32)
-        rows[cid[VOp.VMIN]] = jnp.minimum(a32, b32).astype(dt)
-        rows[cid[VOp.VMAX]] = jnp.maximum(a32, b32).astype(dt)
-        # unsigned min/max compare SEW-bit zero-extensions (truncation-
-        # invariant) and return the original lane values
-        au, bu = s2.astype(udt), b.astype(udt)
-        rows[cid[VOp.VMINU]] = jnp.where(au <= bu, s2, b)
-        rows[cid[VOp.VMAXU]] = jnp.where(au >= bu, s2, b)
-        sh = bu % udt(sew)
-        rows[cid[VOp.VSLL]] = (au << sh).astype(dt)
-        rows[cid[VOp.VSRL]] = (au >> sh).astype(dt)
-        rows[cid[VOp.VSRA]] = s2 >> sh.astype(dt)
-        rows[cid[VOp.VMACC]] = dst + s2 * b
-        rows[cid[VOp.VMV]] = b
-        # slides: gather from vs2 at ids -/+ offset; MODE_SLIDE1 inserts
-        # the scalar at the exposed edge element
+        vd = jnp.where(indirect, (sval2 >> 16) & 0xFF, vd_ref[i]) % n_regs
+        vs2 = jnp.where(indirect, (sval2 >> 8) & 0xFF, vs2_ref[i]) % n_regs
+        vs1 = jnp.where(indirect, sval2 & 0xFF, vs1_ref[i]) % n_regs
+        dst, s2, s1 = vrf_ref[vd], vrf_ref[vs2], vrf_ref[vs1]
+        scalar_b = jnp.where(opmode == isa.MODE_VI, imm_ref[i], sval1)
+        b = jnp.where(opmode == isa.MODE_VV, s1, scalar_b)
+        # unsigned order of the SEW-bit zero-extensions
+        au, bu = (s2 & mask) ^ INT_MIN, (b & mask) ^ INT_MIN
+        sh = b & (sew - 1)
+        # slides read vs2 at ids -/+ off, the index clipped to the register
+        # (scan-engine gather semantics); MODE_SLIDE1 inserts the scalar at
+        # the exposed edge element
         off = jnp.where(slide1, 1, scalar_b)
-        idx_up = ids - off
-        g_up = s2[jnp.clip(idx_up, 0, n_elems - 1)]
-        r_up = jnp.where(idx_up >= 0, g_up, dst)
-        rows[cid[VOp.VSLIDEUP]] = jnp.where(
-            slide1 & (ids == 0), sval1.astype(dt), r_up)
-        idx_dn = ids + off
-        g_dn = s2[jnp.clip(idx_dn, 0, n_elems - 1)]
-        r_dn = jnp.where(idx_dn < vl, g_dn, jnp.zeros_like(dst))
-        rows[cid[VOp.VSLIDEDOWN]] = jnp.where(
-            slide1 & (ids == vl - 1), sval1.astype(dt), r_dn)
-        # EMVV writes one element (full-length writeback, ignores VL)
-        rows[cid[VOp.EMVV]] = jnp.where(
-            ids == sval2 % n_elems, sval1.astype(dt), dst)
+        idx = ids - off
+        up = jnp.where(idx > n_elems - 1, _splat_at(s2, ids, n_elems - 1),
+                       _flat_roll(s2, off % size, lane))
+        up = jnp.where(idx >= 0, up, dst)
+        up = jnp.where(slide1 & (ids == 0), sval1, up)
+        idx = ids + off
+        dn = jnp.where(idx < 0, _splat_at(s2, ids, 0),
+                       _flat_roll(s2, -off % size, lane))
+        dn = jnp.where(idx < vl, dn, 0)
+        dn = jnp.where(slide1 & (ids == vl - 1), sval1, dn)
+        val = dst
+        for vop, r in ((VOp.VADD, s2 + b), (VOp.VSUB, s2 - b),
+                       (VOp.VMUL, s2 * b), (VOp.VMACC, dst + s2 * b),
+                       (VOp.VAND, s2 & b), (VOp.VOR, s2 | b),
+                       (VOp.VXOR, s2 ^ b),
+                       (VOp.VMIN, jnp.where(s2 < b, s2, b)),
+                       (VOp.VMINU, jnp.where(au <= bu, s2, b)),
+                       (VOp.VMAX, jnp.where(s2 > b, s2, b)),
+                       (VOp.VMAXU, jnp.where(au >= bu, s2, b)),
+                       (VOp.VSLL, s2 << sh),
+                       (VOp.VSRL, jax.lax.shift_right_logical(s2 & mask, sh)),
+                       (VOp.VSRA, s2 >> sh), (VOp.VMV, b),
+                       (VOp.VSLIDEUP, up), (VOp.VSLIDEDOWN, dn),
+                       # EMVV writes one element, full-length (ignores VL)
+                       (VOp.EMVV, jnp.where(ids == sval2 % n_elems, sval1,
+                                            dst))):
+            val = jnp.where(op == cid[vop], r, val)
         # EMVX (scan-output only), VSETVL and VNOP leave the VRF untouched
-        val = jnp.stack(rows)[op]
         writes = op <= cid[VOp.EMVV]
         vl_eff = jnp.where(op == cid[VOp.EMVV], n_elems, vl)
-        sel = jnp.where(ids < vl_eff, val, dst)     # tail-undisturbed
-        elems_out_ref[0, vd] = jnp.where(writes, sel, dst)
+        vrf_ref[vd] = jnp.where(writes & (ids < vl_eff), _trunc(val, sew),
+                                dst)              # tail-undisturbed
         return jnp.where(op == cid[VOp.VSETVL],
-                         jnp.minimum(sval1, vlmax), vl)
+                         jnp.minimum(sval1, n_elems), vl)
 
-    jax.lax.fori_loop(0, op_ref.shape[1], step, jnp.int32(vlmax))
+    vl_ref[0] = jax.lax.fori_loop(0, CHUNK, step, vl_ref[0])
 
 
 @functools.lru_cache(maxsize=None)
-def _carus_call(sew: int, n_instr: int, n_tiles: int, n_regs: int,
-                reg_words: int, interpret: bool):
-    L = 32 // sew
-    n_elems = reg_words * L
-    vlmax = reg_words * (32 // sew)
-    ispec = pl.BlockSpec((1, n_instr), lambda t: (t, 0))
-    idspec = pl.BlockSpec((n_elems,), lambda t: (0,))
-    espec = pl.BlockSpec((1, n_regs, n_elems), lambda t: (t, 0, 0))
+def _carus_call(sew: int, n_tiles: int, n_instr: int, n_regs: int,
+                n_elems: int, interpret: bool):
+    vspec = pl.BlockSpec((None, n_regs, ROWS, LANES),
+                         lambda t, c: (t, 0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_carus_kernel, sew=sew, n_regs=n_regs,
-                          vlmax=vlmax),
-        grid=(n_tiles,),
-        in_specs=[ispec] * 8 + [idspec, espec],
-        out_specs=espec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, n_regs, n_elems),
-                                       JNP_DTYPES[sew]),
-        input_output_aliases={9: 0},
+        functools.partial(_carus_kernel, sew=sew, n_elems=n_elems),
+        grid=(n_tiles, n_instr // CHUNK),
+        in_specs=[_stream_spec(n_instr)] * len(CARUS_FIELDS) + [vspec],
+        out_specs=vspec,
+        out_shape=jax.ShapeDtypeStruct((n_tiles, n_regs, ROWS, LANES),
+                                       jnp.int32),
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        input_output_aliases={len(CARUS_FIELDS): 0},
+        compiler_params=_PARAMS,
         interpret=interpret)
+
+
+def carus_wave(batch_state, arrays: dict, *, sew: int, interpret: bool):
+    """``[T, n_regs, reg_words]`` VRFs + ``[T, n]`` stream fields -> final
+    VRFs: unpack to element tiles, one ``pallas_call``, pack back."""
+    cols, n_instr = _flat_stream(arrays, CARUS_FIELDS, NOP_OP_ID["carus"])
+    n_tiles, n_regs, reg_words = batch_state.shape
+    n_lanes = 32 // sew
+    n_elems = reg_words * n_lanes
+    assert n_elems <= ROWS * LANES and n_regs & (n_regs - 1) == 0, \
+        (n_regs, reg_words, sew)
+    elems = alu.unpack(batch_state, sew).reshape(n_tiles, n_regs, n_elems)
+    elems = jnp.pad(elems, ((0, 0), (0, 0), (0, ROWS * LANES - n_elems)))
+    call = _carus_call(sew, n_tiles, n_instr, n_regs, n_elems, interpret)
+    out = call(*cols, elems.reshape(n_tiles, n_regs, ROWS, LANES))
+    out = out.reshape(n_tiles, n_regs, ROWS * LANES)[..., :n_elems]
+    return alu.pack(out.reshape(n_tiles, n_regs, reg_words, n_lanes), sew)
 
 
 # ---------------------------------------------------------------------------
@@ -264,10 +360,10 @@ class _PallasMixin:
     """Shared plumbing: per-(sew, tiles) jit cache, scan_fn/run adapters."""
 
     backend = "pallas"
+    _wave = None
 
-    def _init_backend(self, interpret):
-        self.interpret = default_interpret() if interpret is None \
-            else bool(interpret)
+    def _init_backend(self):
+        self.interpret = interpret_mode()
         self._fns: dict = {}
 
     def batched_fn(self, sew: int, n_tiles: int, donate: bool = False):
@@ -277,7 +373,8 @@ class _PallasMixin:
         key = (sew, n_tiles, donate)
         fn = self._fns.get(key)
         if fn is None:
-            fn = jax.jit(functools.partial(self._run_batch, sew, n_tiles),
+            fn = jax.jit(functools.partial(type(self)._wave, sew=sew,
+                                           interpret=self.interpret),
                          donate_argnums=(0,) if donate else ())
             self._fns[key] = fn
         return fn
@@ -300,46 +397,22 @@ class _PallasMixin:
 
 
 class PallasCaesarEngine(_PallasMixin, CaesarTile):
-    """NM-Caesar tile on the Pallas fast path: the 8192-word memory image
-    resident as ``[mem_words, L]`` native-dtype lanes for the whole
-    instruction stream."""
+    """NM-Caesar tile on the Pallas path: the 8192-word memory image
+    resident in SMEM for the whole instruction stream."""
 
-    def __init__(self, config=None, interpret: bool | None = None):
+    _wave = staticmethod(caesar_wave)
+
+    def __init__(self, config=None):
         super().__init__(config)
-        self._init_backend(interpret)
-
-    def _run_batch(self, sew, n_tiles, batch_state, arrays):
-        dt = JNP_DTYPES[sew]
-        call = _caesar_call(sew, int(arrays["op"].shape[-1]), n_tiles,
-                            self.sim.cfg.mem_words, self.interpret)
-        lanes = alu.unpack(batch_state, sew).astype(dt)
-        out = call(arrays["op"], arrays["dest"], arrays["src1"],
-                   arrays["src2"], lanes)
-        return alu.pack(out.astype(jnp.int32), sew)
+        self._init_backend()
 
 
 class PallasCarusEngine(_PallasMixin, CarusTile):
-    """NM-Carus tile on the Pallas fast path: the VRF resident as
-    ``[n_regs, n_elems]`` native-dtype element rows, VL carried through
-    the ``fori_loop``."""
+    """NM-Carus tile on the Pallas path: the VRF resident in VMEM as one
+    int32 element tile per register, VL carried through the stream."""
 
-    def __init__(self, config=None, interpret: bool | None = None):
+    _wave = staticmethod(carus_wave)
+
+    def __init__(self, config=None):
         super().__init__(config)
-        self._init_backend(interpret)
-
-    def _run_batch(self, sew, n_tiles, batch_state, arrays):
-        dt = JNP_DTYPES[sew]
-        cfg = self.sim.cfg
-        L = 32 // sew
-        call = _carus_call(sew, int(arrays["op"].shape[-1]), n_tiles,
-                           cfg.n_regs, cfg.reg_words, self.interpret)
-        elems = alu.unpack(batch_state, sew).astype(dt).reshape(
-            batch_state.shape[0], cfg.n_regs, cfg.reg_words * L)
-        ids = jnp.arange(cfg.reg_words * L, dtype=jnp.int32)
-        out = call(arrays["op"], arrays["vd"], arrays["vs1"], arrays["vs2"],
-                   arrays["sval1"], arrays["sval2"], arrays["imm"],
-                   arrays["mode"], ids, elems)
-        words = alu.pack(out.reshape(batch_state.shape[0], cfg.n_regs,
-                                     cfg.reg_words, L).astype(jnp.int32),
-                         sew)
-        return words
+        self._init_backend()

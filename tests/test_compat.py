@@ -1,15 +1,7 @@
-"""JAX version-compat shims: mesh axis_types + cost_analysis shape.
-
-The repo supports both JAX 0.4.x and newer:
-* ``jax.sharding.AxisType`` does not exist on 0.4.x — ``launch.mesh`` only
-  passes ``axis_types`` when it does (``make_mesh`` is the single compat
-  constructor everything builds meshes through),
-* ``compiled.cost_analysis()`` returns a one-element list of dicts on 0.4.x
-  and a plain dict on newer JAX — ``hlo_analysis.normalize_cost_analysis``
-  hides the difference.
-
-Both API shapes are exercised here via monkeypatching, plus the real
-installed-JAX path for each shim.
+"""The installed JAX's mesh, shard_map and cost_analysis entry points, as
+the repo calls them: ``launch.mesh.make_mesh`` (every mesh is built
+through it), ``distributed.context.shard_map`` and
+``hlo_analysis.normalize_cost_analysis``.
 """
 
 import numpy as np
@@ -22,28 +14,12 @@ from repro.launch import mesh as mesh_mod
 
 
 # ---------------------------------------------------------------------------
-# axis_types feature detection
+# meshes
 # ---------------------------------------------------------------------------
 
-def test_axis_types_kw_without_axistype(monkeypatch):
-    """JAX 0.4.x shape: no AxisType attribute -> no axis_types kwarg."""
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    assert mesh_mod._axis_types_kw(2) == {}
-
-
-def test_axis_types_kw_with_axistype(monkeypatch):
-    """Newer-JAX shape: AxisType present -> one Auto entry per axis."""
-    class FakeAxisType:
-        Auto = "auto"
-
-    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType,
-                        raising=False)
-    assert mesh_mod._axis_types_kw(3) == {"axis_types": ("auto",) * 3}
-
-
 def test_make_mesh_on_installed_jax():
-    """The compat constructor must build a usable Mesh on whatever JAX is
-    installed (this is the call the subprocess test scripts make)."""
+    """make_mesh builds a usable Mesh (the call the subprocess test
+    scripts make)."""
     mesh = mesh_mod.make_mesh(
         np.asarray(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
     assert mesh.axis_names == ("data", "model")
@@ -57,12 +33,12 @@ def test_make_local_mesh_on_installed_jax():
 
 
 # ---------------------------------------------------------------------------
-# shard_map compat (jax.shard_map vs jax.experimental.shard_map)
+# shard_map
 # ---------------------------------------------------------------------------
 
 def test_shard_map_compat_runs_on_installed_jax():
-    """context.shard_map must dispatch a psum on whatever JAX is installed
-    (the call the MoE layer and the pipeline schedule make)."""
+    """context.shard_map dispatches a psum (the call the MoE layer and the
+    pipeline schedule make)."""
     from repro.distributed import context
 
     mesh = mesh_mod.make_mesh(np.asarray(jax.devices()[:1]), ("data",))
@@ -74,47 +50,23 @@ def test_shard_map_compat_runs_on_installed_jax():
     assert out.shape == (4,)
 
 
-def test_shard_map_compat_prefers_public_api(monkeypatch):
-    """When jax.shard_map exists (newer JAX) it is used with check_vma."""
-    from repro.distributed import context
-
-    calls = {}
-
-    def fake_shard_map(fn, *, mesh, in_specs, out_specs, check_vma):
-        calls["check_vma"] = check_vma
-        return lambda *a: "new-api"
-
-    monkeypatch.setattr(jax, "shard_map", fake_shard_map, raising=False)
-    out = context.shard_map(lambda x: x, mesh=None, in_specs=(),
-                            out_specs=())()
-    assert out == "new-api" and calls["check_vma"] is False
-
-
 # ---------------------------------------------------------------------------
 # cost_analysis normalization
 # ---------------------------------------------------------------------------
 
 def test_normalize_cost_analysis_dict_shape():
-    """Newer-JAX shape: dict passes through (copied)."""
+    """The dict passes through (copied)."""
     src = {"flops": 10.0, "bytes accessed": 5.0}
     out = hlo_analysis.normalize_cost_analysis(src)
     assert out == src and out is not src
 
 
-def test_normalize_cost_analysis_list_shape():
-    """JAX 0.4.x shape: one-element list of dicts unwraps to the dict."""
-    out = hlo_analysis.normalize_cost_analysis([{"flops": 7.0}])
-    assert out == {"flops": 7.0}
-
-
 def test_normalize_cost_analysis_empty():
-    assert hlo_analysis.normalize_cost_analysis([]) == {}
     assert hlo_analysis.normalize_cost_analysis(None) == {}
 
 
 def test_normalize_cost_analysis_real_compiled():
-    """End-to-end on the installed JAX: whatever cost_analysis() returns,
-    the normalized view exposes positive matmul flops."""
+    """End to end: the normalized view exposes positive matmul flops."""
     compiled = jax.jit(lambda a, b: a @ b).lower(
         jax.ShapeDtypeStruct((8, 8), jnp.float32),
         jax.ShapeDtypeStruct((8, 8), jnp.float32)).compile()

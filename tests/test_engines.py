@@ -186,6 +186,16 @@ def _carus_golden_cases():
         carus_entry(VOp.VSLIDEDOWN, vd=11, vs2=2, sval1=3, mode=isa.MODE_VX),
         carus_entry(VOp.VSLIDEDOWN, vd=12, vs2=2, sval1=-9,
                     mode=isa.MODE_VX | isa.MODE_SLIDE1)]))
+    # offsets past either end of the register: the scan engine clips the
+    # gather index, so the far edge element repeats
+    cases.append(("vslide_clip", pre + [
+        carus_entry(VOp.VSLIDEUP, vd=16, vs2=2, sval1=-7, mode=isa.MODE_VX),
+        carus_entry(VOp.VSLIDEDOWN, vd=17, vs2=3, sval1=-11,
+                    mode=isa.MODE_VX),
+        carus_entry(VOp.VSLIDEUP, vd=18, vs2=2, sval1=5000,
+                    mode=isa.MODE_VX),
+        carus_entry(VOp.VSLIDEDOWN, vd=19, vs2=3, imm=-3,
+                    mode=isa.MODE_VI)]))
     cases.append(("emvv_emvx", pre + [
         carus_entry(VOp.EMVV, vd=13, sval1=99, sval2=17),
         carus_entry(VOp.EMVX, vd=0, vs2=2, sval1=5)]))

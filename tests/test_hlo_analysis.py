@@ -30,10 +30,8 @@ def test_scan_flops_expanded():
     assert abs(r["flops"] - expected) / expected < 0.01, \
         (r["flops"], expected)
 
-    # and the body-once XLA number would be ~1/k of that (cost_analysis()
-    # returns a list-of-dicts on JAX 0.4.x — normalized by the helper)
-    cost = hlo_analysis.normalize_cost_analysis(
-        jax.jit(fn).lower(x, w).compile().cost_analysis())
+    # and the body-once XLA number would be ~1/k of that
+    cost = jax.jit(fn).lower(x, w).compile().cost_analysis()
     assert cost["flops"] < r["flops"] / (k_steps - 1)
 
 

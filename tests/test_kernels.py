@@ -35,6 +35,31 @@ def test_nmc_matmul_shapes(m, k, n, bm, bn, bk, act):
                                rtol=1e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("m,k,n", [
+    (4, 352, 200),      # K, N not multiples of 128: both zero-pad
+    (5, 384, 1408),     # N = 11 * 128: bn falls to 128, odd M pads to 32
+    (33, 2816, 256),    # qwen down-projection K = 22 * 128: bk = 256
+    (200, 256, 384),    # a prefill M that is no multiple of the M tile
+    (300, 128, 128),    # M past one default tile: pads to two
+])
+def test_nmc_matmul_any_shape_exact(m, k, n):
+    """Shapes off the default tiles pad instead of asserting; int32
+    accumulation stays exact (unit scale, no bias), and the served
+    epilogue matches the reference."""
+    x = jnp.asarray(RNG.integers(-127, 128, (m, k), dtype=np.int8))
+    w = jnp.asarray(RNG.integers(-127, 128, (k, n), dtype=np.int8))
+    one = jnp.ones((n,), jnp.float32)
+    got = nmc_matmul(x, w, one, None, interpret=True)
+    assert got.shape == (m, n)
+    assert (np.asarray(got) == np.asarray(ref.nmc_matmul(x, w, one))).all()
+    s = jnp.asarray(RNG.uniform(1e-3, 1e-2, n).astype(np.float32))
+    b = jnp.asarray(RNG.normal(size=n).astype(np.float32))
+    got = nmc_matmul(x, w, s, b, act="silu", interpret=True)
+    exp = ref.nmc_matmul(x, w, s, b, act="silu")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
+                               rtol=1e-5, atol=1e-4)
+
+
 def test_nmc_matmul_int32_accumulation_exact():
     """Accumulation must be exact int32 (the paper's 32-bit MAC rule):
     worst-case +-127*127*K must not saturate or lose precision."""
@@ -166,6 +191,23 @@ def test_flash_attention_configs(b, hq, hkv, sq, skv, d, causal, win):
     got = flash_attention(q, k, v, causal=causal, window=win, bq=64, bk=128,
                           interpret=True)
     exp = ref.attention(q, k, v, causal=causal, window=win)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(exp), atol=2e-5)
+
+
+@pytest.mark.parametrize("sq,skv,causal", [
+    (7, 7, True),        # a short prompt: tiles shrink to 16 x 128
+    (200, 200, True),    # past one q tile: q and kv both pad
+    (130, 130, True),
+    (7, 200, False),     # padded keys must stay masked without causality
+])
+def test_flash_attention_any_length(sq, skv, causal):
+    """Prompt lengths off the default tiles pad instead of asserting."""
+    q = jnp.asarray(RNG.normal(size=(1, 4, sq, 64)).astype(np.float32))
+    k = jnp.asarray(RNG.normal(size=(1, 2, skv, 64)).astype(np.float32))
+    v = jnp.asarray(RNG.normal(size=(1, 2, skv, 64)).astype(np.float32))
+    got = flash_attention(q, k, v, causal=causal, interpret=True)
+    assert got.shape == q.shape
+    exp = ref.attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), atol=2e-5)
 
 

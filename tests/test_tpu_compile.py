@@ -1,0 +1,109 @@
+"""Ahead-of-time compiles of the main-path kernels for a TPU v5e that is
+described, not attached.
+
+The TPU compiler runs here without a chip: each case lowers and compiles a
+kernel at the shape the system really runs, so a tiling, SMEM or VMEM fault
+that interpret mode cannot see fails here.  Nothing runs; a compile that
+passes says nothing about results or times.
+
+* the Pallas tile engines at SEW 8/16/32 and tile bucket 4, at the
+  largest instruction bucket the registry kernels and the resident block
+  produce and at the smallest (a stream shorter than one chunk);
+* ``nmc_matmul`` at the five qwen1.5-0.5b projection shapes, at decode
+  (M = 4 slots) and at one prefill length;
+* ``flash_attention`` at prompt lengths 7 and 200.
+
+The topology is described inside a module fixture (never at import): only
+the worker that runs this file loads the TPU library.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.nmc_matmul import nmc_matmul
+from repro.nmc import pallas_engine as pe
+
+SEWS = (8, 16, 32)
+LARGEST_BUCKET = 16384      # Table V gemm / resident-block Caesar streams
+TILE_BUCKET = 4
+# qwen1.5-0.5b (configs/qwen1_5_0_5b.py): d_model 1024, d_ff 2816, vocab
+# 151936; (K, N, bias, act) per projection
+QWEN_PROJECTIONS = {
+    "qkv": (1024, 1024, True, "none"),
+    "o": (1024, 1024, False, "none"),
+    "up": (1024, 2816, False, "none"),
+    "gate": (1024, 2816, False, "silu"),
+    "down": (2816, 1024, False, "none"),
+    "head": (1024, 151936, False, "none"),
+}
+QWEN_HEADS, QWEN_HEAD_DIM = 16, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _sds(sharding, shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()     # the kernel is there
+    return compiled
+
+
+@pytest.mark.parametrize("n_instr", (1, LARGEST_BUCKET),
+                         ids=("smallest", "largest"))
+@pytest.mark.parametrize("sew", SEWS)
+@pytest.mark.parametrize("engine", ("caesar", "carus"))
+def test_engine_wave_compiles(one_chip, engine, sew, n_instr):
+    if engine == "caesar":
+        state = _sds(one_chip, (TILE_BUCKET, 8192))
+        fields, wave = pe.CAESAR_FIELDS, pe.caesar_wave
+    else:
+        state = _sds(one_chip, (TILE_BUCKET, 32, 256))
+        fields, wave = pe.CARUS_FIELDS, pe.carus_wave
+    stream = {f: _sds(one_chip, (TILE_BUCKET, n_instr)) for f in fields}
+    _compile(functools.partial(wave, sew=sew, interpret=False), state, stream)
+
+
+@pytest.mark.parametrize("m", (4, 200), ids=("decode", "prefill"))
+@pytest.mark.parametrize("proj", sorted(QWEN_PROJECTIONS))
+def test_nmc_matmul_compiles_at_qwen_widths(one_chip, proj, m):
+    k, n, bias, act = QWEN_PROJECTIONS[proj]
+    args = [_sds(one_chip, (m, k), jnp.int8), _sds(one_chip, (k, n), jnp.int8),
+            _sds(one_chip, (n,), jnp.float32)]
+    if bias:
+        args.append(_sds(one_chip, (n,), jnp.float32))
+    _compile(functools.partial(nmc_matmul, act=act,
+                               out_dtype=jnp.bfloat16), *args)
+
+
+@pytest.mark.parametrize("s", (7, 200))
+def test_flash_attention_compiles_at_prompt_length(one_chip, s):
+    shape = (1, QWEN_HEADS, s, QWEN_HEAD_DIM)
+    qkv = [_sds(one_chip, shape, jnp.bfloat16)] * 3
+    _compile(functools.partial(flash_attention, causal=True), *qkv)
