@@ -1,0 +1,8 @@
+"""Device time of the NM-Carus Pallas kernel over the real (non-NOP)
+instructions submitted to NM-Carus tiles in the traced window."""
+
+from bench import kernels
+
+
+def read(ctx):
+    return kernels.ns_per_instr(ctx, "carus")
