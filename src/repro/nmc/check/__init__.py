@@ -53,7 +53,8 @@ The passes are numpy-vectorized (event sort over def/use streams, not a
 per-instruction Python loop) and :func:`verify_lowered` memoizes its
 verdict on a content fingerprint of the program, so
 ``nmc.jit(fn, check="error")`` — the default — verifies every lowering
-at a few percent overhead (``benchmarks/check_bench.py`` is the gate).
+in the ``frontend.verify`` span, whose host time per call the benchmark
+reads as ``frontend.verify_ms``.
 
 These analyses are also the substrate of the IR optimizer
 (:mod:`repro.nmc.opt`): every rewrite re-runs them as its

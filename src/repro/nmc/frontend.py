@@ -69,6 +69,7 @@ from repro.core import isa
 from repro.core.isa import CaesarOp, VOp
 from repro.nmc.program import Program, caesar_entry, carus_entry, instr_bucket
 from repro.nmc.registry import BINOPS, NmcRuntime, default_runtime
+from repro.spans import new_call, span
 
 ENGINES = ("caesar", "carus")
 PARTITIONS = ("auto", "rows", "axis")
@@ -1234,8 +1235,9 @@ class CompiledKernel:
 
     # -- pipeline stages -----------------------------------------------------
     def trace(self, *args, sew: Optional[int] = None) -> ProgramBuilder:
-        builder = ProgramBuilder(sew or self.sew, name=self.__name__)
-        self.fn(TileContext(builder), *args)
+        with span("frontend.trace"):
+            builder = ProgramBuilder(sew or self.sew, name=self.__name__)
+            self.fn(TileContext(builder), *args)
         if not builder.stores:
             raise LoweringError(f"kernel '{self.__name__}' stored no "
                                 f"outputs — call t.store(...)")
@@ -1259,20 +1261,23 @@ class CompiledKernel:
               opt: Optional[str] = None) -> LoweredKernel:
         builder = self.trace(*args, sew=sew)
         eng = _check_engine(engine) if engine is not None else self.engine
-        if eng == "auto":
-            eng = select_engine(builder)
-        lk = _LOWERINGS[eng](builder).lower()
+        with span("frontend.lower"):
+            if eng == "auto":
+                eng = select_engine(builder)
+            lk = _LOWERINGS[eng](builder).lower()
         level = self._opt_level(opt)
         if level != "off":
             # optimize before the check= gate so the verifier's report
             # describes the program the engine will actually run; every
             # rewrite was already translation-validated internally
             from repro.nmc import opt as _opt
-            _opt.optimize(lk, level)
+            with span("frontend.opt"):
+                _opt.optimize(lk, level)
         mode = self._check_mode(check)
         if mode != "off":
             from repro.nmc import check as _chk
-            _apply_report(_chk.verify_lowered(lk), mode)
+            with span("frontend.verify"):
+                _apply_report(_chk.verify_lowered(lk), mode)
         return lk
 
     def oracle(self, *args, sew: Optional[int] = None) -> np.ndarray:
@@ -1324,7 +1329,8 @@ class CompiledKernel:
             # compacted wave drops into the smaller bucket as a unit
             from repro.nmc import opt as _opt
             for lk in lks:
-                _opt.optimize(lk, level)
+                with span("frontend.opt"):
+                    _opt.optimize(lk, level)
         for group in sorted({lk.engine for lk in lks}):
             members = [lk for lk in lks if lk.engine == group]
             bucket = instr_bucket(max(lk.program.n_instr
@@ -1336,8 +1342,9 @@ class CompiledKernel:
             # partition safety + per-shard verification, over the *padded*
             # shard programs — the exact wave the scheduler will dispatch
             from repro.nmc import check as _chk
-            _apply_report(_chk.verify_wave(pplan.parent, pplan, lks,
-                                           kernel=self.__name__), cmode)
+            with span("frontend.verify"):
+                _apply_report(_chk.verify_wave(pplan.parent, pplan, lks,
+                                               kernel=self.__name__), cmode)
         return pplan, lks
 
     # -- execution -----------------------------------------------------------
@@ -1383,23 +1390,27 @@ class CompiledKernel:
         the result is a :class:`repro.nmc.runtime.GatherFuture` whose
         ``result()`` reassembles the caller's array (bit-exact vs the
         single-tile path by construction).  Per-tile FIFO order keeps any
-        number of in-flight futures correct either way."""
-        n = self.tiles if tiles is None else _check_tiles(tiles)
-        bk = self.resolve_backend(backend)
-        rt = self.runtime
-        if n == 1:
-            lk = self.lower(*args, engine=engine, opt=opt)
-            return rt.queue.submit(rt.jit_tile, lk.program, image=lk.mem,
-                                   out_slice=lk.out_slice, post=lk.post,
-                                   backend=bk)
-        from repro.nmc.runtime import GatherFuture
-        pplan, lks = self.lower_wave(*args, engine=engine, tiles=n, opt=opt,
-                                     schedule=schedule)
-        futs = [rt.queue.submit(tile, lk.program, image=lk.mem,
-                                out_slice=lk.out_slice, post=lk.post,
-                                backend=bk)
-                for tile, lk in zip(rt.jit_tiles(len(lks)), lks)]
-        return GatherFuture(futs, pplan.gather)
+        number of in-flight futures correct either way.
+
+        The call is one ``frontend.call`` span with a fresh call id, which
+        its work items keep for their dispatch spans (DESIGN.md §15)."""
+        with span("frontend.call", call=new_call()):
+            n = self.tiles if tiles is None else _check_tiles(tiles)
+            bk = self.resolve_backend(backend)
+            rt = self.runtime
+            if n == 1:
+                lk = self.lower(*args, engine=engine, opt=opt)
+                return rt.queue.submit(rt.jit_tile, lk.program, image=lk.mem,
+                                       out_slice=lk.out_slice, post=lk.post,
+                                       backend=bk)
+            from repro.nmc.runtime import GatherFuture
+            pplan, lks = self.lower_wave(*args, engine=engine, tiles=n,
+                                         opt=opt, schedule=schedule)
+            futs = [rt.queue.submit(tile, lk.program, image=lk.mem,
+                                    out_slice=lk.out_slice, post=lk.post,
+                                    backend=bk)
+                    for tile, lk in zip(rt.jit_tiles(len(lks)), lks)]
+            return GatherFuture(futs, pplan.gather)
 
 
 def jit(fn: Optional[Callable] = None, *, engine: str = "auto", sew: int = 8,

@@ -39,6 +39,9 @@ Kernel layout (one shape for both engines):
 * **SEW specialization.**  ``sew`` is static: the
   :class:`repro.nmc.pool.BucketedPool` cache key ``(engine, sew,
   instr-bucket, tile-bucket, backend)`` maps one-to-one onto kernels.
+  Each kernel is named ``caesar_sew<SEW>`` or ``carus_sew<SEW>``: the
+  name the kernel's custom call carries in the compiled HLO and in a
+  device trace.
 
 The same kernels run in Pallas interpret mode when no TPU is attached — the
 CPU test path only (:func:`interpret_mode`); with a TPU attached they always
@@ -207,6 +210,7 @@ def _caesar_call(sew: int, n_tiles: int, n_instr: int, mem_words: int,
         scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
         input_output_aliases={len(CAESAR_FIELDS): 0},
         compiler_params=_PARAMS,
+        name=f"caesar_sew{sew}",
         interpret=interpret)
 
 
@@ -332,6 +336,7 @@ def _carus_call(sew: int, n_tiles: int, n_instr: int, n_regs: int,
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         input_output_aliases={len(CARUS_FIELDS): 0},
         compiler_params=_PARAMS,
+        name=f"carus_sew{sew}",
         interpret=interpret)
 
 

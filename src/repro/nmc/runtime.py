@@ -25,6 +25,12 @@ that duality executable on top of :class:`repro.nmc.pool.ResidentPool`:
   slice (memory-mode read, counted in the pool's ``bytes_moved``), applies
   the build's host-side ``post`` stage, and caches the result.
 
+Each stage is one span (:mod:`repro.spans`, DESIGN.md §15):
+``dispatch.submit``, ``dispatch.stage``, ``dispatch.wait`` and
+``dispatch.extract`` per work item, ``dispatch.launch`` per wave.  An item
+keeps the span ids open at its submit (the ``nmc.jit`` call id), so its
+later spans carry them too.
+
 Two schedulers are pluggable via ``mode``:
 
 * ``"overlapped"`` (default) — eager staging + lazy batched waves: the
@@ -55,6 +61,7 @@ import numpy as np
 from repro.nmc.engine import get_engine
 from repro.nmc.pool import WORD_BYTES, ResidentPool
 from repro.nmc.program import Program
+from repro.spans import current_ids, span
 
 SCHEDULERS = ("inorder", "overlapped")
 
@@ -81,6 +88,7 @@ class NMCFuture:
         self._resolved = False
         self._done = False
         self._seq = None            # key in the queue's outstanding book
+        self.span_ids = current_ids()   # ids of the span open at submit
 
     @property
     def launched(self) -> bool:
@@ -100,7 +108,8 @@ class NMCFuture:
         blocks until the computation is done)."""
         if self._final is None:
             self.queue.flush()
-        out = jax.block_until_ready(self._final)
+        with span("dispatch.wait", **self.span_ids):
+            out = jax.block_until_ready(self._final)
         self._done = True
         return out
 
@@ -110,10 +119,11 @@ class NMCFuture:
         if not self._resolved:
             final = self.state()
             if self.out_slice is not None:
-                elems = get_engine(self.program.engine).extract(
-                    final, self.out_slice, self.program.sew)
-                self.queue._account_store(self.out_slice)
-                self._out = self.post(elems) if self.post else elems
+                with span("dispatch.extract", **self.span_ids):
+                    elems = get_engine(self.program.engine).extract(
+                        final, self.out_slice, self.program.sew)
+                    self.queue._account_store(self.out_slice)
+                    self._out = self.post(elems) if self.post else elems
             self._resolved = True
             self.queue.resolved += 1
             # resolved futures leave the queue's books: only callers who
@@ -249,35 +259,37 @@ class DispatchQueue:
         (after any image install): the steady-state resident-serving path —
         weights stay on the tile, only the per-call activation words move
         (``ResidentPool.patch`` accounting)."""
-        from repro.nmc.check import assert_submittable
-        # last-line structural floor of the static checking contract
-        # (DESIGN.md §11): full verification belongs at lowering time
-        assert_submittable(program)
-        prev = self._last.get(tile)
-        if image is not None and self.mode == "inorder" \
-                and prev is not None and not prev.done:
-            prev.state()            # serial DMA: wait before staging
-        fut = NMCFuture(self, tile, program, out_slice, post)
-        item = _WorkItem(tile, program, image, None, program.engine, fut,
-                         prev, backend, patch)
-        # depth-2 double buffering: at most one staged shadow buffer per
-        # tile ahead of the resident (possibly computing) state
-        if image is not None and not self._staged_pending.get(tile):
-            self._stage(item)
-        self._queued.append(item)
-        self._last[tile] = fut
-        fut._seq = next(self._seq)
-        self._outstanding[fut._seq] = fut
-        self.submitted += 1
-        if self.mode == "inorder":
-            self.flush()
-        return fut
+        with span("dispatch.submit"):
+            from repro.nmc.check import assert_submittable
+            # last-line structural floor of the static checking contract
+            # (DESIGN.md §11): full verification belongs at lowering time
+            assert_submittable(program)
+            prev = self._last.get(tile)
+            if image is not None and self.mode == "inorder" \
+                    and prev is not None and not prev.done:
+                prev.state()            # serial DMA: wait before staging
+            fut = NMCFuture(self, tile, program, out_slice, post)
+            item = _WorkItem(tile, program, image, None, program.engine, fut,
+                             prev, backend, patch)
+            # depth-2 double buffering: at most one staged shadow buffer
+            # per tile ahead of the resident (possibly computing) state
+            if image is not None and not self._staged_pending.get(tile):
+                self._stage(item)
+            self._queued.append(item)
+            self._last[tile] = fut
+            fut._seq = next(self._seq)
+            self._outstanding[fut._seq] = fut
+            self.submitted += 1
+            if self.mode == "inorder":
+                self.flush()
+            return fut
 
     def _stage(self, item: _WorkItem) -> None:
         """Start the async host->device copy into the tile's shadow buffer
         (memory-mode DMA); counted as overlapped when the tile's previous
         program is still unresolved at this moment."""
-        item.staged = get_engine(item.engine).init_state(item.image)
+        with span("dispatch.stage", **item.future.span_ids):
+            item.staged = get_engine(item.engine).init_state(item.image)
         item.image = None
         self._staged_pending[item.tile] = \
             self._staged_pending.get(item.tile, 0) + 1
@@ -304,24 +316,30 @@ class DispatchQueue:
             self._launch_wave(wave)
 
     def _launch_wave(self, wave: list[_WorkItem]) -> None:
-        for it in wave:             # buffer swap: shadow -> resident state
-            if it.image is not None:
-                self._stage(it)     # deferred staging (shadow was occupied)
-            if it.staged is not None:
-                self.pool.install(it.tile, it.engine, it.staged)
-                self._staged_pending[it.tile] -= 1
-            if it.patch is not None:
-                # partial memory-mode write on top of the resident state
-                # (after any install, so patch words win over image words)
-                self.pool.patch(it.tile, it.patch)
-        by_backend: dict[str, list[_WorkItem]] = {}
-        for it in wave:
-            by_backend.setdefault(it.backend, []).append(it)
-        for backend, items in by_backend.items():
-            self.pool.dispatch([(it.tile, it.program) for it in items],
-                               backend=backend)
-        for it in wave:             # capture this wave's final state per item
-            it.future._final = self.pool.state(it.tile)
+        # the wave's spans carry its items' ids where they all agree
+        ids = wave[0].future.span_ids
+        if any(it.future.span_ids != ids for it in wave):
+            ids = {}
+        with span("dispatch.launch", **ids):
+            for it in wave:         # buffer swap: shadow -> resident state
+                if it.image is not None:
+                    self._stage(it)  # deferred staging (shadow was occupied)
+                if it.staged is not None:
+                    self.pool.install(it.tile, it.engine, it.staged)
+                    self._staged_pending[it.tile] -= 1
+                if it.patch is not None:
+                    # partial memory-mode write on top of the resident
+                    # state (after any install, so patch words win over
+                    # image words)
+                    self.pool.patch(it.tile, it.patch)
+            by_backend: dict[str, list[_WorkItem]] = {}
+            for it in wave:
+                by_backend.setdefault(it.backend, []).append(it)
+            for backend, items in by_backend.items():
+                self.pool.dispatch([(it.tile, it.program) for it in items],
+                                   backend=backend)
+            for it in wave:         # capture the wave's final state per item
+                it.future._final = self.pool.state(it.tile)
         self.launched += len(wave)
         self.waves += 1
         if len({it.program.engine for it in wave}) > 1:

@@ -55,6 +55,7 @@ from repro.nmc.frontend import (ENGINES, LoweringError, ProgramBuilder,
                                 UnsupportedOnEngine, _check_tiles,
                                 _ConstScalar, _LOWERINGS, _Node,
                                 engine_diagnosis, select_engine)
+from repro.spans import span
 
 #: The valid ``schedule=`` mode names (a :class:`SchedulePlan` instance is
 #: also accepted wherever a mode is).
@@ -561,8 +562,10 @@ def realize(builder: ProgramBuilder,
             f"shards, partition produced {pplan.n_shards}")
     pplan = pplan.reordered(splan.order)
     engines = [splan.engines[i] for i in splan.order]
-    lks = [_LOWERINGS[e](sb).lower()
-           for e, sb in zip(engines, pplan.builders)]
+    lks = []
+    for e, sb in zip(engines, pplan.builders):
+        with span("frontend.lower"):
+            lks.append(_LOWERINGS[e](sb).lower())
     return pplan, lks
 
 
@@ -576,23 +579,26 @@ def plan_wave(builder: ProgramBuilder, tiles: int, *,
     chunking and tail placement), ``"auto"`` (the full autotuner search)
     or an explicit :class:`SchedulePlan`.  Searches are memoized in the
     content-keyed plan registry; a cache hit returns the identical plan
-    object and only re-lowers the shards for the fresh traced values."""
-    tiles = _check_tiles(tiles)
-    if isinstance(mode, SchedulePlan):
-        pplan, lks = realize(builder, mode)
-        return mode, pplan, lks
-    if mode not in SCHEDULE_MODES:
-        raise ValueError(f"unknown schedule mode {mode!r}: expected a "
-                         f"SchedulePlan or one of {SCHEDULE_MODES}")
-    key = plan_key(builder, tiles, partition, engine, mode)
-    hit = _plan_cache.get(key)
-    if hit is not None:
-        _plan_cache.move_to_end(key)
-        pplan, lks = realize(builder, hit)
-        return hit, pplan, lks
-    splan, pplan, lks = _search(builder, tiles, partition, engine, mode)
-    _cache_put(key, splan)
-    return splan, pplan, lks
+    object and only re-lowers the shards for the fresh traced values.
+    One ``frontend.schedule`` span; the shard lowerings of a realized
+    plan nest in it as ``frontend.lower`` spans."""
+    with span("frontend.schedule"):
+        tiles = _check_tiles(tiles)
+        if isinstance(mode, SchedulePlan):
+            pplan, lks = realize(builder, mode)
+            return mode, pplan, lks
+        if mode not in SCHEDULE_MODES:
+            raise ValueError(f"unknown schedule mode {mode!r}: expected a "
+                             f"SchedulePlan or one of {SCHEDULE_MODES}")
+        key = plan_key(builder, tiles, partition, engine, mode)
+        hit = _plan_cache.get(key)
+        if hit is not None:
+            _plan_cache.move_to_end(key)
+            pplan, lks = realize(builder, hit)
+            return hit, pplan, lks
+        splan, pplan, lks = _search(builder, tiles, partition, engine, mode)
+        _cache_put(key, splan)
+        return splan, pplan, lks
 
 
 def autotune(builder: ProgramBuilder, tiles: int, *,

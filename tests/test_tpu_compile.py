@@ -8,7 +8,8 @@ passes says nothing about results or times.
 
 * the Pallas tile engines at SEW 8/16/32 and tile bucket 4, at the
   largest instruction bucket the registry kernels and the resident block
-  produce and at the smallest (a stream shorter than one chunk);
+  produce and at the smallest (a stream shorter than one chunk), and the
+  name each engine's custom call carries (``caesar_sew8`` ...);
 * ``nmc_matmul`` at the five qwen1.5-0.5b projection shapes, at decode
   (M = 4 slots) and at one prefill length;
 * ``flash_attention`` at prompt lengths 7 and 200.
@@ -88,6 +89,31 @@ def test_engine_wave_compiles(one_chip, engine, sew, n_instr):
         fields, wave = pe.CARUS_FIELDS, pe.carus_wave
     stream = {f: _sds(one_chip, (TILE_BUCKET, n_instr)) for f in fields}
     _compile(functools.partial(wave, sew=sew, interpret=False), state, stream)
+
+
+@pytest.mark.parametrize("sew", (8, 32))
+@pytest.mark.parametrize("engine", ("caesar", "carus"))
+def test_engine_kernel_carries_its_name(one_chip, engine, sew):
+    """The engine's custom call is named ``<engine>_sew<SEW>`` in the
+    compiled HLO, so the trace's operation name (that HLO line) is matched
+    by name, not by the unnamed-call shape fallback of ``bench/kernels``."""
+    from bench.kernels import is_kernel
+    if engine == "caesar":
+        state = _sds(one_chip, (TILE_BUCKET, 8192))
+        fields, wave = pe.CAESAR_FIELDS, pe.caesar_wave
+    else:
+        state = _sds(one_chip, (TILE_BUCKET, 32, 256))
+        fields, wave = pe.CARUS_FIELDS, pe.carus_wave
+    stream = {f: _sds(one_chip, (TILE_BUCKET, 1)) for f in fields}
+    text = _compile(functools.partial(wave, sew=sew, interpret=False),
+                    state, stream).as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert calls
+    other = "carus" if engine == "caesar" else "caesar"
+    for op in calls:
+        assert op.startswith(f"%{engine}_sew{sew}."), op[:80]
+        assert is_kernel(engine)(op) and not is_kernel(other)(op)
 
 
 @pytest.mark.parametrize("m", (4, 200), ids=("decode", "prefill"))
