@@ -34,8 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.nmc.engine import get_engine, resolve_backend
-from repro.nmc.program import (PROG_DTYPE, Program, instr_bucket,
-                               stack_programs)
+from repro.nmc.program import (FIELD_MAPS, PROG_DTYPE, Program,
+                               instr_bucket, pack_programs, stack_programs)
 
 WORD_BYTES = 4
 
@@ -67,6 +67,7 @@ class TilePool:
 
     def __init__(self, donate: bool = False, backend: str = "scan"):
         self._cache: dict[tuple, object] = {}
+        self._group_fns: dict[tuple, object] = {}
         self._donate = donate
         self.backend = resolve_backend(backend)
         self.compiles = 0          # distinct traces (cache misses)
@@ -93,6 +94,36 @@ class TilePool:
                              donate_argnums=(0,) if self._donate else ())
             self._cache[key] = fn
             self.compiles += 1
+        return fn
+
+    def _group_fn(self, shape_key: tuple, n_tiles: int,
+                  backend: str | None = None):
+        """``(states: tuple of n_tiles arrays, packed[F, n_tiles, n]) ->
+        tuple of n_tiles final states`` — one jitted device call per bucket
+        group for :meth:`ResidentPool.dispatch`: the tile states stack, the
+        :func:`pack_programs` stream splits into its fields, and the lanes
+        split back, all inside the trace around :meth:`_batched_fn`'s
+        executor.  Cached under the same key, so it adds no compile key;
+        nothing is donated (the stack inside the trace is a fresh buffer,
+        so the executor still updates it in place)."""
+        backend = self.backend if backend is None \
+            else resolve_backend(backend)
+        key = (*shape_key, n_tiles, backend)
+        fn = self._group_fns.get(key)
+        if fn is None:
+            batched = self._batched_fn(shape_key, n_tiles, backend)
+            # trace the executor's own function: a jit nested in this one
+            # lowers up to 2.5x slower on the chip (NM-Carus), and its
+            # donation means nothing inside a trace
+            batched = getattr(batched, "__wrapped__", batched)
+            fields = [eng for eng, _ in FIELD_MAPS[shape_key[0]]]
+
+            def run_group(states, packed):
+                final = batched(jnp.stack(states),
+                                dict(zip(fields, packed)))
+                return tuple(final[t] for t in range(n_tiles))
+
+            fn = self._group_fns[key] = jax.jit(run_group)
         return fn
 
     @property
@@ -215,9 +246,8 @@ class ResidentPool:
     programs execute against the resident state (compute mode) with only
     instruction streams crossing the host/device boundary, and results are
     *stored* back explicitly (memory-mode read).  Between dispatches the
-    per-tile state lives on device; the batched executor donates the stacked
-    state buffer (``donate_argnums``) so XLA reuses the tile-memory
-    allocation in place.
+    per-tile state lives on device, and each bucket group of a wave runs
+    as one device call (:meth:`dispatch`).
 
     Accounting: ``bytes_moved`` counts only explicit host<->device traffic —
     ``load`` (full image), ``dispatch`` (instruction bytes), ``store``
@@ -236,6 +266,8 @@ class ResidentPool:
         self.stores = 0
         self.dispatches = 0
         self.dispatch_calls = 0      # dispatch() invocations (launch waves)
+        self.stream_uploads = 0      # host->device stream transfers: one
+                                     # packed array per bucket group
         self.programs_run = 0
         self.bytes_moved = 0
         self.patches = 0             # partial memory-mode writes (word spans)
@@ -318,6 +350,14 @@ class ResidentPool:
         uploaded (counted in ``bytes_moved``).  ``backend`` overrides the
         wrapped pool's default executor ("scan"/"pallas") for this wave.
 
+        Each bucket group is one device call (:meth:`TilePool._group_fn`)
+        fed with the tiles' resident states and the group's NOP-padded
+        streams packed into one int32 array (:func:`pack_programs`): one
+        stream upload per group, counted in ``stream_uploads`` (equal to
+        ``dispatches``).  The per-tile inputs are not donated: a tile's
+        previous future may still hold its state, and padding lanes alias
+        lane 0.
+
         One dispatch is one parallel step across the tile array, so a tile
         may appear at most once per call — chained programs on one tile are
         sequential ``dispatch`` calls (each sees the previous final state).
@@ -348,14 +388,11 @@ class ResidentPool:
             states = [self._state[t] for t in tiles]
             states += [states[0]] * (tb - len(tiles))
             progs += [progs[0]] * (tb - len(tiles))
-            batch_state = jnp.stack(states)
-            batch_arrays = {k: jnp.asarray(v)
-                            for k, v in stack_programs(progs).items()}
-            fn = self.pool._batched_fn(progs[0].shape_key, tb,
-                                       backend=backend)
-            final = fn(batch_state, batch_arrays)    # stays on device
-            for t, tile in enumerate(tiles):
-                self._state[tile] = final[t]
+            fn = self.pool._group_fn(progs[0].shape_key, tb, backend=backend)
+            final = fn(tuple(states), pack_programs(progs))  # stays on device
+            for tile, state in zip(tiles, final):
+                self._state[tile] = state
+            self.stream_uploads += 1
             self.dispatches += 1
             self.programs_run += len(tiles)
             self.bytes_moved += tb * bucket * PROG_DTYPE.itemsize

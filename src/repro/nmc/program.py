@@ -47,6 +47,8 @@ _CARUS_FIELD_MAP = (("op", "op"), ("vd", "dest"), ("vs1", "src1"),
                     ("imm", "imm"), ("mode", "mode"))
 _CAESAR_FIELD_MAP = (("op", "op"), ("dest", "dest"), ("src1", "src1"),
                      ("src2", "src2"))
+#: ``(engine field, IR field)`` pairs per engine, in the engine's order.
+FIELD_MAPS = {"caesar": _CAESAR_FIELD_MAP, "carus": _CARUS_FIELD_MAP}
 
 
 def caesar_entry(op: CaesarOp, dest: int = 0, src1: int = 0,
@@ -199,8 +201,7 @@ class Program:
 
     # -- lowering ------------------------------------------------------------
     def field_map(self) -> tuple:
-        return (_CAESAR_FIELD_MAP if self.engine == "caesar"
-                else _CARUS_FIELD_MAP)
+        return FIELD_MAPS[self.engine]
 
     def lower_np(self) -> dict[str, np.ndarray]:
         """Engine-facing dict of int32 numpy arrays (engine field names)."""
@@ -246,3 +247,16 @@ def stack_programs(programs: list[Program]) -> dict[str, np.ndarray]:
     fields = programs[0].field_map()
     return {eng_name: np.stack([p.entries[ir_name] for p in programs])
             for eng_name, ir_name in fields}
+
+
+def pack_programs(programs: list[Program]) -> np.ndarray:
+    """Same-shape programs as one contiguous int32 ``[F, T, n_instr]``
+    array, fields in ``field_map()`` order: a bucket group's whole stream
+    in a single host->device transfer (``ResidentPool.dispatch``)."""
+    key = programs[0].shape_key
+    assert all(p.shape_key == key for p in programs), \
+        [p.shape_key for p in programs]
+    cols = [PROG_DTYPE.names.index(ir) for _, ir in programs[0].field_map()]
+    words = np.stack([p.entries for p in programs]).view(np.int32).reshape(
+        len(programs), key[2], len(PROG_DTYPE.names))
+    return np.ascontiguousarray(words[..., cols].transpose(2, 0, 1))

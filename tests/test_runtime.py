@@ -108,6 +108,137 @@ def test_tile_bucket_matches_instr_bucket_rule():
 
 
 # ---------------------------------------------------------------------------
+# One device call per bucket group in ResidentPool.dispatch
+# ---------------------------------------------------------------------------
+
+_STATE_SHAPE = {"caesar": (8192,), "carus": (32, 256)}
+
+
+def _image(engine: str, seed: int) -> np.ndarray:
+    rng = np.random.default_rng(seed)
+    return rng.integers(-2**31, 2**31, _STATE_SHAPE[engine], dtype=np.int32)
+
+
+def _prog(engine: str, n_instr: int, sew: int = 8) -> Program:
+    return (_caesar_prog if engine == "caesar" else _carus_prog)(n_instr, sew)
+
+
+@pytest.mark.parametrize("engine", ("caesar", "carus"))
+def test_pack_programs_matches_stack_programs(engine):
+    """The packed ``[F, T, n]`` stream holds exactly the per-field stacks,
+    in ``field_map()`` order."""
+    from repro.nmc.program import pack_programs, stack_programs
+    progs = [_prog(engine, 8).pad_to(8), _prog(engine, 5).pad_to(8)]
+    packed = pack_programs(progs)
+    stacked = stack_programs(progs)
+    assert packed.dtype == np.int32 and packed.flags.c_contiguous
+    assert packed.shape == (len(progs[0].field_map()), 2, 8)
+    for i, (field, _) in enumerate(progs[0].field_map()):
+        assert np.array_equal(packed[i], stacked[field])
+
+
+@pytest.mark.parametrize("via", ("dispatch", "queue"))
+def test_resident_dispatch_one_upload_per_bucket_group(via):
+    """A mixed wave of three bucket groups — caesar 5/6/7 instructions
+    (bucket 8, 3 tiles -> tile bucket 4), caesar 3 (bucket 4, 1 tile) and
+    carus 3/4 (bucket 4, 2 tiles) — makes one stream upload per group and
+    leaves every other counter at its hand-computed value."""
+    rp = ResidentPool(pool=BucketedPool(donate=True))
+    wave = [(("c", i), _caesar_prog(n)) for i, n in enumerate((5, 6, 7, 3))]
+    wave += [(("k", i), _carus_prog(n)) for i, n in enumerate((3, 4))]
+    images = {t: np.zeros(_STATE_SHAPE[p.engine], np.int32) for t, p in wave}
+    if via == "dispatch":
+        for t, p in wave:
+            rp.load(t, p.engine, images[t])
+        rp.dispatch(wave)
+    else:
+        queue = DispatchQueue(pool=rp)
+        for t, p in wave:
+            queue.submit(t, p, image=images[t])
+        queue.flush()
+        assert queue.waves == 1
+    assert rp.stream_uploads == rp.dispatches == 3
+    assert rp.dispatch_calls == 1 and rp.programs_run == 6
+    assert rp.compiles == 3 and rp.loads == 6 and rp.stores == 0
+    e = PROG_DTYPE.itemsize
+    state_b = 8192 * 4                   # both engines hold 32 KiB
+    assert rp.bytes_moved == 6 * state_b + (4 * 8 + 1 * 4 + 2 * 4) * e
+    # [4 tiles x 8 - (5+6+7)] + [1 x 4 - 3] + [2 x 4 - (3+4)]
+    assert rp.pool.pad_waste == 14 + 1 + 1
+    assert rp.pool.useful_instrs == 18 + 3 + 7
+
+
+@pytest.mark.parametrize("backend", ("scan", "pallas"))
+@pytest.mark.parametrize("engine", ("caesar", "carus"))
+def test_resident_dispatch_bit_exact_with_padding_lanes(engine, backend):
+    """Three real tiles in a tile bucket of four: each tile's final state
+    equals its program run alone; the padding lane (a copy of lane 0)
+    writes nothing back."""
+    rp = ResidentPool(backend=backend)
+    eng = get_engine(engine)
+    progs = [_prog(engine, n) for n in (5, 6, 7)]
+    for i in range(3):
+        rp.load(i, engine, _image(engine, i))
+    rp.load("idle", engine, _image(engine, 9))
+    rp.dispatch(list(enumerate(progs)))
+    assert rp.dispatches == rp.stream_uploads == 1
+    for i, p in enumerate(progs):
+        want = eng.run(eng.init_state(_image(engine, i)), p)
+        assert np.array_equal(np.asarray(rp.state(i)), np.asarray(want)), i
+    assert np.array_equal(np.asarray(rp.state("idle")), _image(engine, 9))
+
+
+@pytest.mark.parametrize("n_tiles", (1, 3))
+@pytest.mark.parametrize("engine", ("caesar", "carus"))
+def test_chained_dispatch_leaves_earlier_future_intact(engine, n_tiles):
+    """A chained program (no image) takes the previous wave's final state
+    as input while that wave's future is unresolved; the input is not
+    donated, so the earlier future still reads its own result — also when
+    the tile is lane 0 of a padded group."""
+    eng = get_engine(engine)
+    queue = DispatchQueue(pool=ResidentPool(pool=_SHARED))
+    first, second = [], []
+    for t in range(n_tiles):
+        img = _image(engine, 20 + t)
+        first.append(queue.submit(t, _prog(engine, 5), image=img,
+                                  out_slice=(0, 64)))
+        second.append(queue.submit(t, _prog(engine, 3), out_slice=(0, 64)))
+    queue.flush()
+    assert queue.waves == 2 and not any(f.resolved for f in first)
+    for t in range(n_tiles):
+        assert not first[t]._final.is_deleted()
+        s1 = eng.run(eng.init_state(_image(engine, 20 + t)),
+                     _prog(engine, 5))
+        s2 = eng.run(s1, _prog(engine, 3))
+        assert np.array_equal(second[t].result(),
+                              eng.extract(s2, (0, 64), 8))
+        assert np.array_equal(first[t].result(),
+                              eng.extract(s1, (0, 64), 8))
+
+
+@pytest.mark.parametrize("n_tiles", (3, None))
+def test_group_calls_compile_once_across_passes(n_tiles):
+    """A second pass of a mixed library compiles nothing: the pool's
+    compile count and every group call's trace cache stay where the first
+    pass left them (states staged from images and states handed on from
+    earlier calls share one trace)."""
+    builds = _small_builds()
+    pool = BucketedPool(donate=True)
+    queue = DispatchQueue(pool=ResidentPool(pool=pool))
+    first = queue.run_builds(builds, n_tiles=n_tiles)
+    compiles = pool.compiles
+    traces = {k: fn._cache_size() for k, fn in pool._group_fns.items()}
+    assert set(traces.values()) == {1} and len(traces) == compiles
+    again = queue.run_builds(builds, n_tiles=n_tiles)
+    assert pool.compiles == compiles
+    assert {k: fn._cache_size() for k, fn in pool._group_fns.items()} \
+        == traces
+    assert queue.pool.stream_uploads == queue.pool.dispatches
+    for a, b in zip(first, again):
+        assert np.array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
 # Async futures vs synchronous dispatch: bit-exact (acceptance, Table V)
 # ---------------------------------------------------------------------------
 

@@ -12,7 +12,9 @@ passes says nothing about results or times.
   name each engine's custom call carries (``caesar_sew8`` ...);
 * ``nmc_matmul`` at the five qwen1.5-0.5b projection shapes, at decode
   (M = 4 slots) and at one prefill length;
-* ``flash_attention`` at prompt lengths 7 and 200.
+* ``flash_attention`` at prompt lengths 7 and 200;
+* ``ResidentPool.dispatch``'s group call (stack, engine kernel, split) at
+  16 tiles, with the kernel's name and no donated input.
 
 The topology is described inside a module fixture (never at import): only
 the worker that runs this file loads the TPU library.
@@ -114,6 +116,33 @@ def test_engine_kernel_carries_its_name(one_chip, engine, sew):
     for op in calls:
         assert op.startswith(f"%{engine}_sew{sew}."), op[:80]
         assert is_kernel(engine)(op) and not is_kernel(other)(op)
+
+
+@pytest.mark.parametrize("engine", ("caesar", "carus"))
+def test_resident_group_call_compiles(one_chip, monkeypatch, engine):
+    """``ResidentPool.dispatch``'s one call per bucket group, at the
+    benchmark's 16 tiles: it compiles, holds one engine kernel under the
+    engine's name, and aliases no input to an output (nothing donated)."""
+    from repro.nmc import engine as nmc_engine
+    from repro.nmc.pool import BucketedPool
+    eng = (pe.PallasCaesarEngine if engine == "caesar"
+           else pe.PallasCarusEngine)()
+    eng.interpret = False
+    monkeypatch.setattr(nmc_engine, "_DEFAULT_ENGINES",
+                        {(engine, "pallas"): eng})
+    tiles, bucket = 16, 1024
+    fn = BucketedPool(donate=True, backend="pallas")._group_fn(
+        (engine, 8, bucket), tiles)
+    shape = (8192,) if engine == "caesar" else (32, 256)
+    n_fields = len(pe.CAESAR_FIELDS if engine == "caesar"
+                   else pe.CARUS_FIELDS)
+    text = fn.lower(tuple(_sds(one_chip, shape) for _ in range(tiles)),
+                    _sds(one_chip, (n_fields, tiles, bucket))
+                    ).compile().as_text()
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 1 and f"%{engine}_sew8." in calls[0], calls
+    assert "input_output_alias" not in text.splitlines()[0]
 
 
 @pytest.mark.parametrize("m", (4, 200), ids=("decode", "prefill"))
