@@ -13,6 +13,9 @@ program and for the control put in its place:
 * NMC cells: mismatched output elements of the program, and of the
   saturating reference, against the wrapping reference.
 
+With ``--fault <name>`` (serving cells) one of :data:`FAULTS` is planted
+in the program first, and the reading is the program's under that fault.
+
 Prints one JSON line per seed.  The benchmark's own runs never run this.
 """
 
@@ -32,7 +35,45 @@ for _p in (ROOT / "src", ROOT):
         sys.path.insert(0, str(_p))
 
 
-def serve_reading(cfg, traffic, seed, seconds):
+def stale_cache(E):
+    """A decode step that returns the KV cache it was given: generated
+    tokens never enter it (a state left unchanged)."""
+    orig = E.make_decode_step
+
+    def make(cfg):
+        step = orig(cfg)
+
+        def decode(params, tokens, caches, cache_len):
+            logits, _ = step(params, tokens, caches, cache_len)
+            return logits, caches
+        return decode
+    return E, "make_decode_step", make
+
+
+def token_altered(E):
+    """Every third engine step changes the token that each slot got from
+    it, where it is produced (also in slots that finished in that step)."""
+    orig = E.ServeEngine.step
+    count = [0]
+
+    def step(self):
+        before = {id(r): len(r.out) for r in self.slot_req if r is not None}
+        out = orig(self)
+        count[0] += 1
+        if count[0] % 3 == 0:
+            for req in self.slot_req + self.done[-self.n_slots:]:
+                if req is not None and before.get(id(req), 0) > 0:
+                    req.out[-1] = (req.out[-1] + 1) % self.cfg.vocab_size
+        return out
+    return E.ServeEngine, "step", step
+
+
+# Faults planted in the serving program: each takes ``repro.serve.engine``
+# and returns the ``(object, attribute, value)`` that plants it.
+FAULTS = {"stale_cache": stale_cache, "token_altered": token_altered}
+
+
+def serve_reading(cfg, traffic, seed, seconds, control=True):
     from bench import costs, harness as H
     from bench.drivers import serve_closed_loop as S
 
@@ -44,8 +85,9 @@ def serve_reading(cfg, traffic, seed, seconds):
     reqs = S.sample(loop.finished, traffic, seed)
     del engine, loop
     gc.collect()
-    res = S.check(params, cfg, traffic, reqs, control=True)
-    return {"program": res["max_gap"], "control": res["control_max_gap"],
+    res = S.check(params, cfg, traffic, reqs, control=control)
+    return {"program": res["max_gap"],
+            "control": res.get("control_max_gap"),
             "tokens": res["tokens"],
             "median_top2_spacing": res["median_top2_spacing"],
             "end_to_end": e2e, "setup_s": h.setup_s}
@@ -79,6 +121,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True,
                     help="comma-separated seeds")
     ap.add_argument("--seconds", type=float, default=5.0)
+    ap.add_argument("--fault", choices=sorted(FAULTS),
+                    help="plant this fault in the serving program")
     args = ap.parse_args(argv)
 
     from bench import harness as H
@@ -90,13 +134,20 @@ def main(argv=None) -> int:
     cfg = H.load_config(centry)
     traffic = H.load_traffic(cell["traffic"])
     driver = H.load_driver(traffic["driver"])
+    serving = traffic["driver"] == "serve_closed_loop"
+    if args.fault:
+        if not serving:
+            ap.error("--fault plants a fault in the serving program")
+        from repro.serve import engine as E
+        setattr(*FAULTS[args.fault](E))
     for seed in (int(s) for s in args.seeds.split(",")):
         t = time.perf_counter()
-        if traffic["driver"] == "serve_closed_loop":
-            r = serve_reading(cfg, traffic, seed, args.seconds)
+        if serving:
+            r = serve_reading(cfg, traffic, seed, args.seconds,
+                              control=not args.fault)
         else:
             r = nmc_reading(cfg, traffic, seed, args.seconds, driver)
-        r.update(workload=args.workload, seed=seed,
+        r.update(workload=args.workload, seed=seed, fault=args.fault,
                  device=devs[0].device_kind,
                  seconds=time.perf_counter() - t)
         print(json.dumps(r), flush=True)

@@ -13,7 +13,9 @@ Requests come from the seed in blocks of ``block``: every block holds the
 same prompt lengths and output lengths (fixed shares of the buckets,
 output lengths at fixed quantiles of their distribution), in an order
 and with token ids drawn from the seed.  So every seed asks for the same
-work.
+work.  A mix that names an ``order_seed`` draws the order from it
+instead, the same for every seed: in a closed loop the order decides
+which admissions share a step, and with it the tail of TTFT.
 
 Correctness: once the window has closed and the engine is freed, a
 sample of the finished requests drawn from the seed, the longest among
@@ -67,14 +69,17 @@ def output_lengths(spec: dict, block: int) -> list[int]:
 
 
 def requests(traffic: dict, vocab: int, seed: int):
-    """Endless stream of ``(prompt, max_new)`` drawn from the seed."""
+    """Endless stream of ``(prompt, max_new)`` drawn from the seed (the
+    order from the mix's ``order_seed`` where it names one)."""
     rng = np.random.default_rng([seed, 7])
+    order = np.random.default_rng(int(traffic["order_seed"])) \
+        if "order_seed" in traffic else rng
     block = int(traffic["block"])
     prompts = np.repeat(traffic["prompt_buckets"],
                         block_counts(traffic["prompt_weights"], block))
     outs = np.asarray(output_lengths(traffic["output"], block))
     while True:
-        p, o = rng.permutation(prompts), rng.permutation(outs)
+        p, o = order.permutation(prompts), order.permutation(outs)
         for n, m in zip(p, o):
             yield rng.integers(0, vocab, int(n), dtype=np.int32), int(m)
 

@@ -64,6 +64,18 @@ def test_cell_resolves_every_piece(cell):
         assert m["moves"] in [e["name"] for e in e2e]
 
 
+@pytest.mark.parametrize("kind", ["end_to_end", "per_layer"])
+def test_metric_workloads_name_existing_cells(kind):
+    for m in BM[kind]:
+        assert set(m.get("workloads", CELLS)) <= set(CELLS), m["name"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_reports_an_end_to_end_metric_besides_setup(cell):
+    e2e = [m["name"] for m in H.metrics_of(BM, cell, "end_to_end")]
+    assert set(e2e) - {"setup_s"}, cell
+
+
 def test_every_config_is_used():
     used = {w["config"] for w in BM["workloads"]}
     assert used == {c["name"] for c in BM["configs"]}

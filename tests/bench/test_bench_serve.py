@@ -7,7 +7,7 @@ import time
 import numpy as np
 import pytest
 
-from bench import harness as H
+from bench import control, harness as H
 from bench.drivers import lm_weights, serve_closed_loop as S
 from bench.ref import qwen as qref
 
@@ -17,11 +17,17 @@ SMOKE = dict(FULL, hidden_size=64, intermediate_size=128,
              num_hidden_layers=2, num_attention_heads=4,
              num_key_value_heads=4, vocab_size=512)
 CHAT = H.load_traffic("chat")
+# The chip's limits were read at the published size, where the 4-bit
+# control reads 3.4 SD or more; at this size it reads 1.3-2.9 and the
+# program under 0.2, so the smoke runs keep a limit of their own.
 TRAFFIC = dict(CHAT, clients=4, n_slots=4, max_len=128,
                prompt_buckets=[8, 24], prompt_weights=[0.5, 0.5], block=10,
                output={"dist": "lognormal", "median": 8, "sigma": 0.5,
                        "min": 2, "max": 32},
-               ramp_steps=4, check_tokens=60)
+               ramp_steps=4, check_tokens=60,
+               limits={"max_logit_gap_sd": 1.0})
+CHIP = json.loads((H.ROOT / "tests" / "bench" / "data" /
+                   "serve_control_v5e.json").read_text())
 
 
 def _run(seed=2**31 + 17, seconds=1.0):
@@ -46,6 +52,8 @@ def test_every_seed_asks_for_the_same_work(name):
             sorted(len(p) for p, _ in a[blk]) == \
             sorted(len(p) for p, _ in c[blk])
         assert sorted(m for _, m in a[blk]) == sorted(m for _, m in c[blk])
+    same_order = [(len(p), m) for p, m in a] == [(len(p), m) for p, m in c]
+    assert same_order == ("order_seed" in tr)
     assert not all(np.array_equal(x[0], y[0]) for x, y in zip(a, c))
     for p, m in a:
         assert len(p) + m <= tr["max_len"]
@@ -56,9 +64,35 @@ def test_block_shares_follow_the_weights():
     assert S.block_counts([0.35, 0.25, 0.20, 0.12, 0.08], 100) == \
         [35, 25, 20, 12, 8]
     assert S.block_counts([0.5, 0.3, 0.2], 10) == [5, 3, 2]
+    assert S.block_counts(CHAT["prompt_weights"], 100) == [52, 20, 15, 8, 5]
     lens = S.output_lengths(CHAT["output"], 100)
-    assert 120 < np.median(lens) < 136
-    assert min(lens) >= 16 and max(lens) <= 512
+    assert 150 < np.median(lens) < 172
+    assert min(lens) >= 16 and sorted(lens)[-7:] == [507] + [512] * 6
+
+
+def test_chat_mix_is_fitted_to_its_source():
+    """The chat mix's numbers follow from the moments its source gives: a
+    lognormal with the published mean and standard deviation of tokens per
+    prompt and per response; a prompt is served at the smallest bucket
+    that holds it, and the share over the largest bucket is cut to it."""
+    import math
+    import statistics
+
+    def fit(moments):
+        m, sd = moments["mean"], moments["sd"]
+        s2 = math.log(1 + (sd / m) ** 2)
+        return statistics.NormalDist(math.log(m) - s2 / 2, math.sqrt(s2))
+
+    prompt = fit(CHAT["fit"]["prompt_tokens"])
+    cdf = [prompt.cdf(math.log(b)) for b in CHAT["prompt_buckets"][:-1]]
+    shares = np.diff([0.0] + cdf + [1.0])
+    assert np.allclose(CHAT["prompt_weights"], shares, atol=1e-4)
+    out = fit(CHAT["fit"]["response_tokens"])
+    assert math.isclose(CHAT["output"]["median"], math.exp(out.mean),
+                        abs_tol=0.01)
+    assert math.isclose(CHAT["output"]["sigma"], out.stdev, abs_tol=1e-4)
+    assert CHAT["prompt_buckets"][-1] + CHAT["output"]["max"] == \
+        CHAT["max_len"]
 
 
 def test_sample_takes_the_longest_and_several_requests():
@@ -129,38 +163,79 @@ def test_control_reads_incorrect():
     assert res["max_gap"] <= limit < res["control_max_gap"], res
 
 
-def test_fault_cache_left_unchanged_reads_incorrect(monkeypatch):
+def _faulty_run_is_incorrect(monkeypatch, capsys, fault, entry):
+    """Plant ``fault`` (``bench/control.py``'s) in the program and run the
+    smoke cell through the driver or through the whole of ``bench/run.py``."""
+    from repro.serve import engine as E
+    monkeypatch.setattr(*control.FAULTS[fault](E))
+    if entry == "driver":
+        res = _run(seed=23)
+        assert not res.correct, [(c.name, c.value) for c in res.compared]
+    else:
+        line = _main_line(monkeypatch, capsys, "qwen05b.chat")
+        assert line["correct"] is False, line["compared"]
+
+
+@pytest.mark.parametrize("entry", ["driver", "run.main"])
+def test_fault_cache_left_unchanged_reads_incorrect(monkeypatch, capsys,
+                                                    entry):
     """Decode returns the KV cache it was given: generated tokens never
     enter it."""
-    from repro.serve import engine as E
-    orig = E.make_decode_step
-
-    def stale(cfg):
-        step = orig(cfg)
-
-        def decode(params, tokens, caches, cache_len):
-            logits, _ = step(params, tokens, caches, cache_len)
-            return logits, caches
-        return decode
-    monkeypatch.setattr(E, "make_decode_step", stale)
-    res = _run(seed=23)
-    assert not res.correct, [(c.name, c.value) for c in res.compared]
+    _faulty_run_is_incorrect(monkeypatch, capsys, "stale_cache", entry)
 
 
-def test_fault_token_altered_reads_incorrect(monkeypatch):
+@pytest.mark.parametrize("entry", ["driver", "run.main"])
+def test_fault_token_altered_reads_incorrect(monkeypatch, capsys, entry):
     """Every third step replaces the token it produced for each slot."""
-    from repro.serve import engine as E
-    orig = E.ServeEngine.step
-    count = [0]
+    _faulty_run_is_incorrect(monkeypatch, capsys, "token_altered", entry)
 
-    def step(self):
-        out = orig(self)
-        count[0] += 1
-        if count[0] % 3 == 0:
-            for req in self.slot_req:
-                if req is not None and req.out:
-                    req.out[-1] = (req.out[-1] + 1) % self.cfg.vocab_size
-        return out
-    monkeypatch.setattr(E.ServeEngine, "step", step)
-    res = _run(seed=29)
-    assert not res.correct, [(c.name, c.value) for c in res.compared]
+
+def _main_line(monkeypatch, capsys, cell):
+    """``bench/run.py`` on the CPU at the smoke size: the look for a chip,
+    the peaks and the compile cache are stood in for, the rest runs."""
+    import jax
+    from bench import run
+
+    monkeypatch.setattr(H, "accelerator", lambda n: jax.devices()[:n])
+    v5e = H.load_peaks("TPU v5 lite")
+    monkeypatch.setattr(H, "load_peaks", lambda kind: v5e)
+    monkeypatch.setattr(H, "enable_compile_cache", lambda: "off")
+    monkeypatch.setattr(H, "load_config", lambda entry: SMOKE)
+    monkeypatch.setattr(H, "load_traffic", lambda name: TRAFFIC)
+    assert run.main(["--workload", cell, "--seed", str(2**31 + 41),
+                     "--seconds", "1", "--trace", "0"]) == 0
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def test_result_line_of_a_serving_cell(monkeypatch, capsys):
+    line = _main_line(monkeypatch, capsys, "qwen05b.chat")
+    assert line["correct"] is True and line["failed"] == 0
+    assert set(line["metrics"]) == {"tokens_per_s", "ttft_p95_ms",
+                                    "itl_p95_ms", "setup_s"}
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == {"max_logit_gap_sd"}
+
+
+@pytest.mark.parametrize("cell", ["qwen05b.chat", "qwen05b.docqa"])
+def test_limit_lies_between_the_chip_readings(cell):
+    """Each serving mix's limit lies above every reading of the program
+    and below every reading of the 4-bit control and of each planted
+    fault, read on the chip over a dozen seeds or more (a fault on three
+    or more), the control's least at three times the program's most or
+    more: a new limit has to bring its readings with it."""
+    limit = H.load_traffic(cell.split(".", 1)[1])["limits"][
+        "max_logit_gap_sd"]
+    rows = [r for r in CHIP["readings"] if r["cell"] == cell]
+    runs = [r for r in CHIP["runs"] if r["cell"] == cell]
+    faults = [r for r in CHIP["faults"] if r["cell"] == cell]
+    assert len({r["seed"] for r in rows}) >= 12
+    assert {r["device"] for r in rows + runs + faults} == {"TPU v5 lite"}
+    program = max(r["program"] for r in rows + runs)
+    control = min(r["control"] for r in rows)
+    assert program < limit < control, (program, limit, control)
+    assert control >= 3 * program
+    for name in {r["fault"] for r in faults}:
+        seen = [r["reading"] for r in faults if r["fault"] == name]
+        assert len(seen) >= 3 and limit < min(seen), (name, seen)
+    if cell in {w["name"] for w in H.load_benchmark()["workloads"]}:
+        assert {r["fault"] for r in faults} >= {"stale_cache"}
