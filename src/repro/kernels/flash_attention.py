@@ -70,13 +70,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_offset",
-                                             "bq", "bk", "interpret"))
+                                             "scale", "bq", "bk",
+                                             "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
-                    q_offset: int = 0, bq: int = 128, bk: int = 512,
+                    q_offset: int = 0, scale: float | None = None,
+                    bq: int = 128, bk: int = 512,
                     interpret: bool = False) -> jax.Array:
     """q: (B, Hq, Sq, D); k: (B, Hkv, Skv, D); v: (B, Hkv, Skv, Dv).
-    Dv may differ from D (MLA)."""
+    Dv may differ from D (MLA).  ``scale`` multiplies the logits
+    (``1/sqrt(D)`` where None)."""
     b, hq, sq, d = q.shape
     dv = v.shape[-1]
     _, hkv, skv, _ = k.shape
@@ -85,7 +88,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bq, bk = min(bq, _round_up(sq, 16)), min(bk, _round_up(skv, 128))
     sqp, skvp = _round_up(sq, bq), _round_up(skv, bk)
     grid = (b * hq, sqp // bq, skvp // bk)
-    scale = float(1.0 / np.sqrt(d))
+    scale = float(1.0 / np.sqrt(d)) if scale is None else float(scale)
 
     pad = ((0, 0), (0, sqp - sq), (0, 0))
     qs = jnp.pad(q.reshape(b * hq, sq, d), pad)

@@ -38,17 +38,28 @@ def nmc_matmul(x_q, w_q, scale, bias=None, *, act: str = "none",
     return ref.nmc_matmul(x_q, w_q, scale, bias, act=act, out_dtype=out_dtype)
 
 
-def attention(q, k, v, *, causal=True, window=None, q_offset=0):
-    """Memory-safe attention: flash kernel on TPU, chunked lax fallback."""
+def nmc_expert_matmul(x_q, w_q, scale, *, act: str = "none",
+                      out_dtype=jnp.bfloat16):
+    """A bank of W8A8 expert GEMMs (leading expert axis), one call."""
+    if backend_is_tpu():
+        return _mm.nmc_expert_matmul(x_q, w_q, scale, act=act,
+                                     out_dtype=out_dtype)
+    return ref.nmc_expert_matmul(x_q, w_q, scale, act=act,
+                                 out_dtype=out_dtype)
+
+
+def attention(q, k, v, *, causal=True, window=None, q_offset=0, scale=None):
+    """Memory-safe attention: flash kernel on TPU, chunked lax fallback.
+    ``scale`` multiplies the logits (``1/sqrt(head dim)`` where None)."""
     if backend_is_tpu():
         return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                                   q_offset=q_offset)
+                                   q_offset=q_offset, scale=scale)
     return chunked_attention(q, k, v, causal=causal, window=window,
-                             q_offset=q_offset)
+                             q_offset=q_offset, scale=scale)
 
 
 def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
-                      kv_chunk: int = 1024):
+                      scale=None, kv_chunk: int = 1024):
     """Online-softmax attention as a lax.scan over KV chunks — the same math
     as the Pallas kernel, expressed in XLA ops.  Never materializes Sq x Skv;
     peak temp is Sq x kv_chunk per head.  Supports dv != dq (MLA)."""
@@ -62,7 +73,8 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     nk = k.shape[2] // kv_chunk
-    scale = 1.0 / jnp.sqrt(jnp.float32(d))
+    scale = 1.0 / jnp.sqrt(jnp.float32(d)) if scale is None \
+        else jnp.float32(scale)
 
     qf = (q.astype(jnp.float32) * scale).reshape(b, hkv, group * sq, d)
     kc = k.reshape(b, hkv, nk, kv_chunk, d).transpose(2, 0, 1, 3, 4)

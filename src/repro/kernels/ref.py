@@ -34,6 +34,16 @@ def nmc_matmul(x_q: jax.Array, w_q: jax.Array, scale: jax.Array,
     return y.astype(out_dtype)
 
 
+def nmc_expert_matmul(x_q: jax.Array, w_q: jax.Array, scale: jax.Array,
+                      act: str = "none", out_dtype=jnp.float32) -> jax.Array:
+    """y[e] = act((x_q[e] @ w_q[e]) * scale[e]): x_q (E, M, K) int8, w_q
+    (E, K, N) int8, scale (E, N) f32; int32 accumulation per expert."""
+    acc = jnp.einsum("emk,ekn->emn", x_q, w_q,
+                     preferred_element_type=jnp.int32)
+    y = acc.astype(jnp.float32) * scale[:, None, :]
+    return apply_act(y, act).astype(out_dtype)
+
+
 def apply_act(y: jax.Array, act: str) -> jax.Array:
     if act == "none":
         return y

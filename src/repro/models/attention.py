@@ -46,7 +46,8 @@ def gqa_qkv(p, x, cfg: ModelConfig, positions):
     v = L.linear(p["wv"], x, nmc_mode=cfg.nmc_mode).reshape(
         b, s, cfg.n_kv_heads, hd)
     if not cfg.learned_pos:
-        cos, sin = L.rope_table(positions, hd, cfg.rope_theta)
+        cos, sin = L.rope_table(positions, hd, cfg.rope_theta,
+                                  cfg.rope_scaling)
         q = L.apply_rope(q, cos, sin)
         k = L.apply_rope(k, cos, sin)
     return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
@@ -83,7 +84,8 @@ def gqa_decode(p, x, cfg: ModelConfig, cache: dict, cache_len) -> tuple:
     v = L.linear(p["wv"], x, nmc_mode=cfg.nmc_mode).reshape(
         b, 1, cfg.n_kv_heads, cfg.head_dim)
     if not cfg.learned_pos:
-        cos, sin = L.rope_table(positions, cfg.head_dim, cfg.rope_theta)
+        cos, sin = L.rope_table(positions, cfg.head_dim, cfg.rope_theta,
+                                  cfg.rope_scaling)
         q = L.apply_rope(q, cos[:, :, None, :], sin[:, :, None, :])
         k = L.apply_rope(k, cos[:, :, None, :], sin[:, :, None, :])
     q = q.transpose(0, 2, 1, 3)
@@ -189,20 +191,29 @@ def mla_init(key, cfg: ModelConfig) -> dict:
     }
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``1/sqrt(qk_nope + qk_rope)``, times YaRN's ``mscale^2`` where the
+    model scales its rope (DeepSeek-V2's ``softmax_scale``)."""
+    s = 1.0 / np.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    return s if cfg.rope_scaling is None else \
+        s * cfg.rope_scaling.softmax_scale
+
+
 def mla_apply(p, x, cfg: ModelConfig, *, q_offset=0) -> jax.Array:
     """Train/prefill: expanded (flash-compatible) formulation."""
     b, s, _ = x.shape
     h = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     positions = jnp.arange(s) + q_offset
-    cos, sin = L.rope_table(positions, dr, cfg.rope_theta)
+    cos, sin = L.rope_table(positions, dr, cfg.rope_theta, cfg.rope_scaling)
 
     q = L.linear(p["wq"], x, nmc_mode=cfg.nmc_mode).reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = L.apply_rope(q_rope, cos, sin)
 
     ckv = L.rmsnorm(p["norm_ckv"],
-                    L.linear(p["w_dkv"], x, nmc_mode=cfg.nmc_mode))
+                    L.linear(p["w_dkv"], x, nmc_mode=cfg.nmc_mode),
+                    cfg.norm_eps)
     k_nope = L.linear(p["w_uk"], ckv, nmc_mode=cfg.nmc_mode).reshape(
         b, s, h, dn)
     v = L.linear(p["w_uv"], ckv, nmc_mode=cfg.nmc_mode).reshape(b, s, h, dv)
@@ -214,7 +225,8 @@ def mla_apply(p, x, cfg: ModelConfig, *, q_offset=0) -> jax.Array:
     q_full = jnp.concatenate([q_nope, q_rope], -1).transpose(0, 2, 1, 3)
     k_full = jnp.concatenate([k_nope, k_rope], -1).transpose(0, 2, 1, 3)
     v_t = v.transpose(0, 2, 1, 3)
-    o = kops.attention(q_full, k_full, v_t, causal=True, q_offset=q_offset)
+    o = kops.attention(q_full, k_full, v_t, causal=True, q_offset=q_offset,
+                       scale=mla_softmax_scale(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
     return L.linear(p["wo"], o, nmc_mode=cfg.nmc_mode)
 
@@ -229,9 +241,11 @@ def mla_prefill(p, x, cfg: ModelConfig, max_len: int) -> tuple:
     b, s, _ = x.shape
     out = mla_apply(p, x, cfg)
     positions = jnp.arange(s)
-    cos, sin = L.rope_table(positions, cfg.qk_rope_dim, cfg.rope_theta)
+    cos, sin = L.rope_table(positions, cfg.qk_rope_dim, cfg.rope_theta,
+                            cfg.rope_scaling)
     ckv = L.rmsnorm(p["norm_ckv"],
-                    L.linear(p["w_dkv"], x, nmc_mode=cfg.nmc_mode))
+                    L.linear(p["w_dkv"], x, nmc_mode=cfg.nmc_mode),
+                    cfg.norm_eps)
     krope = L.apply_rope(
         L.linear(p["w_krope"], x, nmc_mode=cfg.nmc_mode)[:, :, None, :],
         cos, sin)[:, :, 0, :]
@@ -251,14 +265,15 @@ def mla_decode(p, x, cfg: ModelConfig, cache: dict, cache_len) -> tuple:
     r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
                      cfg.v_head_dim)
     positions = (cache_len - 1)[:, None]
-    cos, sin = L.rope_table(positions, dr, cfg.rope_theta)
+    cos, sin = L.rope_table(positions, dr, cfg.rope_theta, cfg.rope_scaling)
 
     q = L.linear(p["wq"], x, nmc_mode=cfg.nmc_mode).reshape(b, 1, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = L.apply_rope(q_rope, cos[:, :, None, :], sin[:, :, None, :])
 
     ckv_new = L.rmsnorm(p["norm_ckv"],
-                        L.linear(p["w_dkv"], x, nmc_mode=cfg.nmc_mode))
+                        L.linear(p["w_dkv"], x, nmc_mode=cfg.nmc_mode),
+                        cfg.norm_eps)
     krope_new = L.apply_rope(
         L.linear(p["w_krope"], x, nmc_mode=cfg.nmc_mode)[:, :, None, :],
         cos[:, :, None, :], sin[:, :, None, :])[:, :, 0, :]
@@ -276,7 +291,7 @@ def mla_decode(p, x, cfg: ModelConfig, cache: dict, cache_len) -> tuple:
         * p["w_uk"]["scale"][None, :]).reshape(r, h, dn)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0].astype(jnp.float32),
                        w_uk.astype(jnp.float32))
-    scale = 1.0 / np.sqrt(dn + dr)
+    scale = mla_softmax_scale(cfg)
     logits = (jnp.einsum("bhr,bsr->bhs", q_lat,
                          ckv_c.astype(jnp.float32))
               + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32),

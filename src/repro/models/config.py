@@ -3,9 +3,41 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+import math
+from typing import Any, Optional
 
 import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN ``rope_scaling`` (DeepSeek-V2's keys): RoPE frequencies
+    interpolated by ``factor`` beyond a ramp between the dimensions that
+    turn ``beta_fast`` and ``beta_slow`` times over the original context,
+    and attention logits scaled by ``softmax_scale``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def get_mscale(scale: float, m: float) -> float:
+        return 1.0 if scale <= 1 else 0.1 * m * math.log(scale) + 1.0
+
+    @property
+    def cos_sin_scale(self) -> float:
+        """Factor on the cos/sin tables."""
+        return self.get_mscale(self.factor, self.mscale) / \
+            self.get_mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        """Factor on ``1/sqrt(head dim)``: ``mscale(mscale_all_dim)^2``."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self.get_mscale(self.factor, self.mscale_all_dim) ** 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -23,6 +55,7 @@ class ModelConfig:
     # attention
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    rope_scaling: Optional[YaRN] = None
     window: int | None = None      # sliding-window attention
     learned_pos: bool = False      # learned absolute positions (whisper)
 
@@ -42,6 +75,11 @@ class ModelConfig:
     capacity_factor: float = 1.25
     first_dense_layers: int = 0
     dense_d_ff: int = 0            # d_ff of the first dense layers
+    norm_topk_prob: bool = True    # renormalize the top-k router weights
+    # the routed experts this chip holds: ``experts_held`` of them from
+    # ``expert_first`` (0 -> all ``n_experts``); the router spans all
+    expert_first: int = 0
+    experts_held: int = 0
 
     # SSM (Mamba2 / hybrid)
     ssm_state: int = 0
@@ -90,6 +128,11 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        return self.expert_first, self.experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:      # mamba2 inner width

@@ -13,6 +13,8 @@ The paper's technique surfaces here as :func:`linear`'s NMC modes:
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -149,11 +151,43 @@ def layernorm(p: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_table(positions: jax.Array, dim: int, theta: float) -> tuple:
-    """cos/sin tables for given positions: (..., dim/2)."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_range(dim: int, theta: float, scaling) -> tuple[int, int]:
+    """The frequency indices between which YaRN's ramp runs: those of the
+    dimensions that turn ``beta_fast`` and ``beta_slow`` times over the
+    original context, clamped to ``[0, dim - 1]``."""
+    def corr(n):
+        return dim * math.log(scaling.original_max_position_embeddings
+                              / (n * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(corr(scaling.beta_fast)), 0),
+            min(math.ceil(corr(scaling.beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling) -> np.ndarray:
+    """YaRN's RoPE inverse frequencies ``(dim/2,)``
+    (:class:`repro.models.config.YaRN`): those past the ramp divided by
+    its factor, linearly in between (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``)."""
+    base = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim))
+    lo, hi = yarn_range(dim, theta, scaling)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    inv = base / scaling.factor * ramp + base * (1 - ramp)
+    return inv.astype(np.float32)
+
+
+def rope_table(positions: jax.Array, dim: int, theta: float,
+               scaling=None) -> tuple:
+    """cos/sin tables for given positions: (..., dim/2); ``scaling`` is a
+    model's YaRN ``rope_scaling`` or None."""
+    if scaling is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32)
+                               / dim))
+    else:
+        inv = jnp.asarray(yarn_inv_freq(dim, theta, scaling))
     ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling.cos_sin_scale != 1.0:
+        cos, sin = cos * scaling.cos_sin_scale, sin * scaling.cos_sin_scale
+    return cos, sin
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -79,16 +79,24 @@ def _block_apply(p, x, cfg: ModelConfig, use_moe: bool):
     return x + L.mlp(p["mlp"], h, cfg.act, nmc_mode=cfg.nmc_mode), jnp.float32(0)
 
 
+def _ffn_serve(p, h, cfg: ModelConfig, use_moe: bool):
+    """The block's MLP or expert layer at prefill and decode: ``(y, rows
+    routed to each held expert)``, the rows None but for served experts."""
+    if not use_moe:
+        return L.mlp(p["mlp"], h, cfg.act, nmc_mode=cfg.nmc_mode), None
+    if MOE.serving(p["moe"], cfg):
+        return MOE.moe_serve(p["moe"], h, cfg)
+    return MOE.moe_apply(p["moe"], h, cfg)[0], None
+
+
 def _block_prefill(p, x, cfg: ModelConfig, use_moe: bool, max_len: int):
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
     pre = A.mla_prefill if cfg.mla else A.gqa_prefill
     y, cache = pre(p["attn"], h, cfg, max_len)
     x = x + y
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    if use_moe:
-        y, _ = MOE.moe_apply(p["moe"], h, cfg)
-        return x + y, cache
-    return x + L.mlp(p["mlp"], h, cfg.act, nmc_mode=cfg.nmc_mode), cache
+    y, rows = _ffn_serve(p, h, cfg, use_moe)
+    return x + y, (cache, rows)
 
 
 def _block_decode(p, x, cfg: ModelConfig, use_moe: bool, cache, cache_len):
@@ -97,10 +105,8 @@ def _block_decode(p, x, cfg: ModelConfig, use_moe: bool, cache, cache_len):
     y, cache = dec(p["attn"], h, cfg, cache, cache_len)
     x = x + y
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    if use_moe:
-        y, _ = MOE.moe_apply(p["moe"], h, cfg)
-        return x + y, cache
-    return x + L.mlp(p["mlp"], h, cfg.act, nmc_mode=cfg.nmc_mode), cache
+    y, rows = _ffn_serve(p, h, cfg, use_moe)
+    return x + y, (cache, rows)
 
 
 # ---------------------------------------------------------------------------
@@ -328,6 +334,14 @@ def loss_fn(params, batch: dict, cfg: ModelConfig,
 # prefill / decode
 # ---------------------------------------------------------------------------
 
+#: The cache entry, beside the layers' state, in which the prefill and
+#: decode programs of a served expert model carry the rows their last call
+#: routed to each held expert, ``(expert layers, held)`` int32.  It holds
+#: no per-slot state: :func:`cache_batch_axes` marks it ``NOT_PER_SLOT``.
+EXPERT_ROWS = "expert_rows"
+NOT_PER_SLOT = -1
+
+
 def init_caches(params, cfg: ModelConfig, batch: int, max_len: int,
                 dtype=jnp.bfloat16) -> dict:
     fam = cfg.family
@@ -342,6 +356,9 @@ def init_caches(params, cfg: ModelConfig, batch: int, max_len: int,
             caches["dense_layers"] = jax.tree.map(
                 lambda x: jnp.broadcast_to(
                     x, (cfg.first_dense_layers,) + x.shape), one)
+        if fam == "moe" and MOE.serving(params["layers"]["moe"], cfg):
+            caches[EXPERT_ROWS] = jnp.zeros(
+                (n_scan, cfg.held_experts[1]), jnp.int32)
         return caches
     if fam == "hybrid":
         groups = cfg.n_layers // cfg.attn_every
@@ -390,7 +407,8 @@ def cache_batch_axes(cfg: ModelConfig, caches: dict) -> dict:
     if fam in ("dense", "vlm", "moe", "encdec"):
         # every entry ("layers", "dense_layers", "cross_k/v") is stacked
         # with one leading layer axis -> batch at 1
-        return {k: const(v, 1) for k, v in caches.items()}
+        return {k: const(v, NOT_PER_SLOT if k == EXPERT_ROWS else 1)
+                for k, v in caches.items()}
     if fam == "hybrid":
         return {"mamba": const(caches["mamba"], 2),
                 "attn": const(caches["attn"], 1)}
@@ -410,19 +428,21 @@ def decode_step(params, tokens, caches: dict, cache_len, cfg: ModelConfig):
         if use_moe and cfg.first_dense_layers:
             def bodyd(h, inp):
                 lp, c = inp
-                h, nc = _block_decode(lp, h, cfg, False, c, cache_len)
+                h, (nc, _) = _block_decode(lp, h, cfg, False, c, cache_len)
                 return h, nc
             x, ncd = jax.lax.scan(bodyd, x, (params["dense_layers"],
                                              caches["dense_layers"]))
 
         def body(h, inp):
             lp, c = inp
-            h, nc = _block_decode(lp, h, cfg, use_moe, c, cache_len)
-            return h, nc
-        x, nc = jax.lax.scan(body, x, (params["layers"], caches["layers"]))
+            return _block_decode(lp, h, cfg, use_moe, c, cache_len)
+        x, (nc, rows) = jax.lax.scan(body, x, (params["layers"],
+                                               caches["layers"]))
         new = {"layers": nc}
         if use_moe and cfg.first_dense_layers:
             new["dense_layers"] = ncd
+        if rows is not None:
+            new[EXPERT_ROWS] = rows
     elif fam == "hybrid":
         shared = params["shared_attn"]
 
@@ -434,7 +454,8 @@ def decode_step(params, tokens, caches: dict, cache_len, cfg: ModelConfig):
                 y, nst = SSM.mamba2_decode(lp, hh, cfg, st)
                 return hh + y, nst
             h, nms = jax.lax.scan(inner, h, (glp, gms))
-            h, nac = _block_decode(shared, h, cfg, False, gac, cache_len)
+            h, (nac, _) = _block_decode(shared, h, cfg, False, gac,
+                                         cache_len)
             return h, (nms, nac)
         x, (nm, na) = jax.lax.scan(
             group, x, (params["mamba"], caches["mamba"], caches["attn"]))
@@ -512,15 +533,19 @@ def prefill(params, batch: dict, cfg: ModelConfig, max_len: int):
         caches = {}
         if use_moe and cfg.first_dense_layers:
             def bodyd(h, lp):
-                return _block_prefill(lp, h, cfg, False, max_len)
+                h, (cache, _) = _block_prefill(lp, h, cfg, False, max_len)
+                return h, cache
             x, cd = jax.lax.scan(_maybe_remat(bodyd, cfg), x,
                                  params["dense_layers"])
             caches["dense_layers"] = cd
 
         def body(h, lp):
             return _block_prefill(lp, h, cfg, use_moe, max_len)
-        x, cs = jax.lax.scan(_maybe_remat(body, cfg), x, params["layers"])
+        x, (cs, rows) = jax.lax.scan(_maybe_remat(body, cfg), x,
+                                     params["layers"])
         caches["layers"] = cs
+        if rows is not None:
+            caches[EXPERT_ROWS] = rows
     elif fam == "hybrid":
         shared = params["shared_attn"]
 

@@ -13,6 +13,13 @@ Runs inside ``context.shard_map`` (``jax.shard_map``) when a mesh is
 active; degrades to a single-shard call otherwise (unit tests).  Capacity-dropped tokens fall back
 to zero contribution from routed experts (shared experts still apply),
 standard top-k capacity semantics.
+
+Serving (:func:`moe_serve`, int8 expert banks under ``nmc_mode="w8a8"``)
+is one chip's share of an expert-parallel deployment: the f32 router
+spans every expert, the chip holds ``cfg.held_experts`` of them and
+computes their part of the result, dropless, each expert's GEMMs W8A8 in
+one ``nmc_expert_matmul`` call per projection.  What absent experts
+would add is left out; the token exchange between chips is not here.
 """
 
 from __future__ import annotations
@@ -24,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import context
+from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 
@@ -100,6 +108,70 @@ def _moe_local(x, wr, wi, wg, wo, *, cfg: ModelConfig, axis: Optional[str]):
         y = jax.lax.psum(y, axis)
         aux = jax.lax.pmean(aux, axis)
     return y, aux
+
+
+def serving(p, cfg: ModelConfig) -> bool:
+    """Whether an expert layer's params are in the served W8A8 form."""
+    return cfg.nmc_mode == "w8a8" and "wi_q" in p
+
+
+def route(x, wr, cfg: ModelConfig):
+    """Softmax over every expert's f32 router logit, then greedy top-k:
+    ``(weights (t, k), experts (t, k))``, the weights renormalized only
+    where ``cfg.norm_topk_prob``."""
+    probs = jax.nn.softmax(x.astype(jnp.float32) @ wr.astype(jnp.float32),
+                           axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_e
+
+
+def _quantize_per_expert(buf):
+    """(E, t, K) -> int8 and one dynamic scale per expert input."""
+    amax = jnp.max(jnp.abs(buf.astype(jnp.float32)), axis=(1, 2))
+    s = jnp.maximum(amax, 1e-8) / 127.0
+    q = jnp.clip(jnp.round(buf.astype(jnp.float32) / s[:, None, None]),
+                 -127, 127).astype(jnp.int8)
+    return q, s
+
+
+def routed_part(p, x, cfg: ModelConfig):
+    """The held experts' part of the routed result for tokens ``x`` (t, d),
+    and the rows routed to each held expert ``(held,)`` int32.
+
+    Dropless: each held expert's buffer has a row for every token, the
+    token where it was routed there and zeros elsewhere, so the shapes
+    are static and nothing overflows.  Rows routed elsewhere are computed
+    too (``moe.pad_share`` counts them); they carry zero weight."""
+    first, held = cfg.held_experts
+    top_p, top_e = route(x, p["router"]["w"], cfg)
+    hit = top_e[:, :, None] == (first + jnp.arange(held))      # (t, k, E_h)
+    gate = jnp.sum(jnp.where(hit, top_p[..., None], 0.0), axis=1)
+    routed = jnp.any(hit, axis=1)                               # (t, E_h)
+    buf = jnp.where(routed.T[:, :, None], x[None], 0).astype(x.dtype)
+    xq, sx = _quantize_per_expert(buf)
+    up = kops.nmc_expert_matmul(xq, p["wi_q"], p["wi_s"] * sx[:, None],
+                                out_dtype=x.dtype)
+    g = kops.nmc_expert_matmul(xq, p["wg_q"], p["wg_s"] * sx[:, None],
+                               act="silu", out_dtype=x.dtype)
+    hq, sh = _quantize_per_expert(up * g)
+    out = kops.nmc_expert_matmul(hq, p["wo_q"], p["wo_s"] * sh[:, None],
+                                 out_dtype=jnp.float32)       # (E_h, t, d)
+    y = jnp.einsum("etd,te->td", out, gate).astype(x.dtype)
+    return y, jnp.sum(routed, axis=0, dtype=jnp.int32)
+
+
+def moe_serve(p, x, cfg: ModelConfig):
+    """Served expert layer, x: (B, S, D) -> (y, rows routed to each held
+    expert): the held experts' routed part plus the shared experts (one
+    W8A8 MLP of their summed width), added once."""
+    b, s, d = x.shape
+    y, rows = routed_part(p, x.reshape(-1, d), cfg)
+    y = y.reshape(b, s, d)
+    if "shared" in p:
+        y = y + L.mlp(p["shared"], x, nmc_mode=cfg.nmc_mode)
+    return y, rows
 
 
 def _expert_weights(p, dtype):

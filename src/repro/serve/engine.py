@@ -15,8 +15,8 @@ queued work through an :class:`repro.nmc.DispatchQueue` from the curated
 ``repro.nmc`` public surface (with ``nmc_mode='w8a8'`` those are exactly
 the int8 NMC projections): the queue launches the computations
 asynchronously and the engine blocks only at future resolution, so a
-batch of admissions issues all its prefills before the first host-side
-cache merge (DESIGN.md §5.2).  By default the engine joins the shared
+batch of admissions issues all its prefills before the first slot
+insert (DESIGN.md §5.2).  By default the engine joins the shared
 :func:`repro.nmc.default_runtime` queue, so serving traffic and
 ``nmc.jit`` kernel calls drain through one dispatch discipline.
 
@@ -107,12 +107,18 @@ class ServeEngine:
         # cache — slot writes must not sniff axes from leaf shapes (a
         # size-1 layer axis is indistinguishable from a size-1 batch axis)
         self._cache_axes = lm.cache_batch_axes(cfg, self.caches)
+        self._insert = jax.jit(_slot_writer(self._cache_axes),
+                               donate_argnums=(0,))
         self.slot_req: list[Optional[Request]] = [None] * n_slots
         self.slot_len = np.zeros(n_slots, np.int32)
         self.slot_remaining = np.zeros(n_slots, np.int32)
         self.slot_last_tok = np.zeros(n_slots, np.int32)
         self.queue: list[Request] = []
         self.done: list[Request] = []
+        # what each program of the last step routed to the held experts of
+        # a served expert model: (rows in the call, rows routed to each
+        # held expert per layer), left on the device; empty otherwise
+        self.expert_rows: list[tuple[int, jax.Array]] = []
 
     # -- NMC tile-array offload ----------------------------------------------
     def nmc_project(self, x8, w8, sew: int = 8) -> np.ndarray:
@@ -202,8 +208,9 @@ class ServeEngine:
         # two-phase admission: launch a prefill for every (free slot, queued
         # request) pair as queued device work first — the dispatch queue's
         # async launches overlap on the device — then resolve the futures
-        # and merge caches host-side.  Bit-identical to admitting one slot
-        # at a time (prefills are independent); only the overlap differs.
+        # and write each cache into its slot.  Bit-identical to admitting
+        # one slot at a time (prefills are independent); only the overlap
+        # differs.
         launches = []
         for s in range(self.n_slots):
             if self.max_prefills is not None \
@@ -217,14 +224,15 @@ class ServeEngine:
                 launches.append((s, req, fut))
         for s, req, fut in launches:
             # .value, not .result(): the arrays are their own futures — the
-            # argmax below forces logits while the cache merge stays queued
+            # argmax below forces logits while the slot insert stays queued
             logits, caches1 = fut.value
-            # copy the single-sequence cache into slot s, on the batch axis
-            # the cache family declares for each leaf (never sniffed from
-            # leaf shapes)
-            self.caches = jax.tree.map(
-                lambda full, one, ax: _insert_slot(full, one, s, ax),
-                self.caches, caches1, self._cache_axes)
+            if lm.EXPERT_ROWS in caches1:
+                self.expert_rows.append((len(req.prompt),
+                                         caches1[lm.EXPERT_ROWS]))
+            # write the single-sequence cache into slot s in place (the
+            # batched cache is donated), on the batch axis the cache family
+            # declares for each leaf (never sniffed from leaf shapes)
+            self.caches = self._insert(self.caches, caches1, s)
             tok = int(jnp.argmax(logits[0]))
             req.out.append(tok)
             self.slot_req[s] = req
@@ -240,6 +248,7 @@ class ServeEngine:
 
     # -- decode loop ----------------------------------------------------------
     def step(self):
+        self.expert_rows = []
         self._admit()
         active = [s for s in range(self.n_slots) if self.slot_req[s]]
         if not active:
@@ -252,6 +261,9 @@ class ServeEngine:
         fut = self.nmc_queue.submit_call(self.decode, self.params, toks,
                                          self.caches, clen)
         logits, self.caches = fut.value
+        if lm.EXPERT_ROWS in self.caches:
+            self.expert_rows.append((self.n_slots,
+                                     self.caches[lm.EXPERT_ROWS]))
         nxt = np.asarray(jnp.argmax(logits, -1))
         for s in active:
             req = self.slot_req[s]
@@ -272,10 +284,22 @@ class ServeEngine:
         return self.done
 
 
-def _insert_slot(full, one, s: int, axis: int):
+def _slot_writer(axes):
+    """``(caches, one, s) -> caches`` with the batch-1 cache tree ``one``
+    written into slot ``s`` of every leaf, on the leaf's batch axis."""
+    def insert(caches, one, s):
+        return jax.tree.map(lambda full, o, ax: _insert_slot(full, o, s, ax),
+                            caches, one, axes)
+    return insert
+
+
+def _insert_slot(full, one, s, axis: int):
     """Write a batch-1 cache entry into slot s of the batched cache along
     the explicit ``axis`` declared by :func:`repro.models.lm.cache_batch_axes`
     (shape sniffing misreads single-layer stacks, whose layer dim of 1 is
-    indistinguishable from a batch dim of 1)."""
+    indistinguishable from a batch dim of 1).  An entry that holds no
+    per-slot state (``lm.NOT_PER_SLOT``) is passed by."""
+    if axis == lm.NOT_PER_SLOT:
+        return full
     return jax.lax.dynamic_update_slice_in_dim(full, one.astype(full.dtype),
                                                s, axis=axis)

@@ -7,7 +7,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.nmc_matmul import nmc_matmul
+from repro.kernels.nmc_matmul import nmc_expert_matmul, nmc_matmul
 from repro.kernels.vrf_alu import make_prog, vrf_alu
 
 RNG = np.random.default_rng(0)
@@ -58,6 +58,40 @@ def test_nmc_matmul_any_shape_exact(m, k, n):
     exp = ref.nmc_matmul(x, w, s, b, act="silu")
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("e,m,k,n,bn,bk", [
+    (3, 40, 256, 384, 128, 128),    # a grid step per expert, row, N, K tile
+    (2, 9, 2048, 1408, 1536, 2048),  # DeepSeek-V2-Lite's up bank, decode
+    (2, 5, 1408, 2048, 1536, 2048),  # its down bank: bn falls to 1024
+])
+@pytest.mark.parametrize("act", ["none", "silu"])
+def test_nmc_expert_matmul_matches_reference(e, m, k, n, bn, bk, act):
+    """Each expert's own weights, scales and activation scale; a bank
+    that no row was routed to (all-zero input) gives zeros."""
+    x = RNG.integers(-127, 128, (e, m, k), dtype=np.int8)
+    x[1] = 0
+    w = jnp.asarray(RNG.integers(-127, 128, (e, k, n), dtype=np.int8))
+    s = jnp.asarray(RNG.uniform(1e-5, 1e-4, (e, n)).astype(np.float32))
+    got = nmc_expert_matmul(jnp.asarray(x), w, s, act=act, bn=bn, bk=bk,
+                            interpret=True)
+    exp = ref.nmc_expert_matmul(jnp.asarray(x), w, s, act=act)
+    assert got.shape == (e, m, n)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
+                               rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got[1]).any()
+
+
+def test_nmc_expert_matmul_is_nmc_matmul_per_expert():
+    """Unit scales: the int32 sums are exact and equal ``nmc_matmul``'s on
+    each expert's slice."""
+    x = jnp.asarray(RNG.integers(-127, 128, (2, 33, 384), dtype=np.int8))
+    w = jnp.asarray(RNG.integers(-127, 128, (2, 384, 256), dtype=np.int8))
+    one = jnp.ones((2, 256), jnp.float32)
+    got = np.asarray(nmc_expert_matmul(x, w, one, interpret=True))
+    for i in range(2):
+        want = np.asarray(ref.nmc_matmul(x[i], w[i], one[i]))
+        assert (got[i] == want).all()
 
 
 def test_nmc_matmul_int32_accumulation_exact():

@@ -161,3 +161,119 @@ def test_param_count_sane():
         cfg = cb.get(arch)
         n = cfg.param_count()
         assert 3e7 < n < 3e10, (arch, n)
+
+
+def test_yarn_follows_deepseek_v2():
+    """DeepSeek-V2-Lite's YaRN: the ramp runs between frequency indices 10
+    and 23 of the 64-wide rope, the softmax scale gains mscale^2, and the
+    frequencies follow the published formula."""
+    import math
+    cfg = cb.get("deepseek-v2-lite-16b")
+    rs, d, b = cfg.rope_scaling, cfg.qk_rope_dim, cfg.rope_theta
+    assert (d, b, rs.factor) == (64, 10000.0, 40.0)
+
+    def corr(n):
+        return d * math.log(4096 / (2 * math.pi * n)) / (2 * math.log(b))
+    lo, hi = math.floor(corr(32)), math.ceil(corr(1))
+    assert (lo, hi) == (10, 23) == L.yarn_range(d, b, rs)
+    mscale = 0.1 * 0.707 * math.log(40) + 1
+    assert mscale == pytest.approx(1.26080, abs=1e-5)
+    assert rs.softmax_scale == pytest.approx(mscale ** 2) \
+        == pytest.approx(1.58963, abs=1e-5)
+    assert rs.cos_sin_scale == 1.0
+    want = []
+    for i in range(d // 2):
+        ramp = min(max((i - lo) / (hi - lo), 0.0), 1.0)
+        f = b ** (-2 * i / d)
+        want.append(f / 40 * ramp + f * (1 - ramp))
+    got = L.yarn_inv_freq(d, b, rs)
+    np.testing.assert_allclose(got, np.float32(want), rtol=1e-6)
+    assert got[lo] == pytest.approx(b ** (-2 * lo / d))       # not yet cut
+    assert got[hi] == pytest.approx(b ** (-2 * hi / d) / 40)  # cut by 40
+    cos, sin = L.rope_table(jnp.arange(5), d, b, rs)
+    np.testing.assert_allclose(np.asarray(cos),
+                               np.cos(np.arange(5)[:, None] * got),
+                               atol=1e-6)
+    from repro.models import attention
+    assert attention.mla_softmax_scale(cfg) == pytest.approx(
+        1.58963 / math.sqrt(192), rel=1e-5)
+
+
+def _served_moe(key, n_experts=16, top_k=4, **kw):
+    """A served (int8 banks, f32 router) expert layer of a small config."""
+    cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=64,
+                      n_heads=4, n_kv_heads=4, d_ff=96, vocab_size=64,
+                      moe=True, n_experts=n_experts, top_k=top_k,
+                      moe_d_ff=48, n_shared_experts=2, nmc_mode="w8a8",
+                      dtype=jnp.float32, **kw)
+    p = L.quantize_tree(moe.moe_init(key, cfg))
+    return cfg, p
+
+
+@pytest.mark.parametrize("norm", [False, True])
+def test_router_softmax_top_k(norm):
+    """Softmax over every expert, greedy top-k; the chosen weights are the
+    softmax's own where ``norm_topk_prob`` is false, renormalized to 1
+    where it is true."""
+    cfg, p = _served_moe(jax.random.PRNGKey(0), norm_topk_prob=norm)
+    x = jax.random.normal(jax.random.PRNGKey(1), (10, 64), jnp.float32)
+    w, e = moe.route(x, p["router"]["w"], cfg)
+    probs = np.asarray(jax.nn.softmax(x @ p["router"]["w"], -1))
+    want_e = np.argsort(-probs, -1)[:, :4]
+    assert (np.asarray(e) == want_e).all()
+    chosen = np.take_along_axis(probs, want_e, -1)
+    if norm:
+        chosen = chosen / chosen.sum(-1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(w), chosen, rtol=1e-5)
+    assert (np.asarray(w).sum(-1) < 0.999).all() != norm
+
+
+def test_expert_shares_add_up_to_the_uncut_layer():
+    """Eight chips' shares of a 16-expert layer (2 experts each): the
+    routed parts every share computes, with the shared experts counted
+    once, add up to the layer computed with every expert held; and every
+    token reaches its top-k experts (nothing dropped)."""
+    cfg, p = _served_moe(jax.random.PRNGKey(2))
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 12, 64), jnp.float32)
+    whole, rows = moe.moe_serve(p, x, cfg)
+    assert int(rows.sum()) == 2 * 12 * cfg.top_k
+    banks = ("wi_q", "wi_s", "wg_q", "wg_s", "wo_q", "wo_s")
+    total = L.mlp(p["shared"], x, nmc_mode="w8a8").reshape(-1, 64)
+    share_rows = []
+    for i in range(8):
+        part = {"router": p["router"],
+                **{k: p[k][2 * i:2 * i + 2] for k in banks}}
+        y, r = moe.routed_part(part, x.reshape(-1, 64),
+                               cfg.scaled(expert_first=2 * i,
+                                          experts_held=2))
+        total = total + y
+        share_rows.append(np.asarray(r))
+    np.testing.assert_allclose(np.asarray(total).reshape(x.shape),
+                               np.asarray(whole), rtol=1e-5, atol=1e-5)
+    assert (np.concatenate(share_rows) == np.asarray(rows)).all()
+
+
+def test_served_experts_drop_no_token():
+    """Every token routed to one expert: its buffer takes them all, and
+    the result is each token's top-k SwiGLU computed one by one."""
+    cfg, p = _served_moe(jax.random.PRNGKey(4), n_experts=8, top_k=2)
+    p["router"]["w"] = p["router"]["w"].at[:, 0].add(50.0)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (1, 24, 64),
+                                  jnp.float32))
+    y, rows = moe.routed_part(p, x[0], cfg)
+    assert int(rows[0]) == 24
+    w, e = moe.route(x[0], p["router"]["w"], cfg)
+
+    def bank(k, i):
+        return np.asarray(p[f"{k}_q"][i], np.float32) * \
+            np.asarray(p[f"{k}_s"][i])[None]
+    want = np.zeros((24, 64), np.float32)
+    for t in range(24):
+        for j in range(2):
+            i, xt = int(e[t, j]), np.asarray(x[0, t])
+            u = (xt @ bank("wi", i)) * np.asarray(
+                jax.nn.silu(xt @ bank("wg", i)))
+            want[t] += float(w[t, j]) * (u @ bank("wo", i))
+    # int8 activations, one scale per expert input: ~1% of the output
+    np.testing.assert_allclose(np.asarray(y), want, rtol=0.05,
+                               atol=0.03 * np.abs(want).max())

@@ -13,6 +13,9 @@ passes says nothing about results or times.
 * ``nmc_matmul`` at the five qwen1.5-0.5b projection shapes, at decode
   (M = 4 slots) and at one prefill length;
 * ``flash_attention`` at prompt lengths 7 and 200;
+* ``nmc_expert_matmul`` at DeepSeek-V2-Lite's expert banks (8 experts,
+  2048 x 1408 and back) at 96 rows (decode) and 512 (the longest
+  prefill), with the kernel's name;
 * ``ResidentPool.dispatch``'s group call (stack, engine kernel, split) at
   16 tiles, with the kernel's name and no donated input.
 
@@ -28,7 +31,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.nmc_matmul import nmc_matmul
+from repro.kernels.nmc_matmul import nmc_expert_matmul, nmc_matmul
 from repro.nmc import pallas_engine as pe
 
 SEWS = (8, 16, 32)
@@ -162,3 +165,25 @@ def test_flash_attention_compiles_at_prompt_length(one_chip, s):
     shape = (1, QWEN_HEADS, s, QWEN_HEAD_DIM)
     qkv = [_sds(one_chip, shape, jnp.bfloat16)] * 3
     _compile(functools.partial(flash_attention, causal=True), *qkv)
+
+
+@pytest.mark.parametrize("m", (96, 512), ids=("decode", "prefill"))
+@pytest.mark.parametrize("k,n,act", ((2048, 1408, "silu"),
+                                     (1408, 2048, "none")),
+                         ids=("up", "down"))
+def test_nmc_expert_matmul_compiles_at_deepseek_widths(one_chip, m, k, n,
+                                                       act):
+    """The held experts' banks of one DeepSeek-V2-Lite layer (8 of 64)
+    in one call; the custom call is named ``nmc_expert_matmul``, which
+    ``nmc_matmul``'s trace name does not match."""
+    from bench.kernels import is_kernel
+    args = [_sds(one_chip, (8, m, k), jnp.int8),
+            _sds(one_chip, (8, k, n), jnp.int8),
+            _sds(one_chip, (8, n), jnp.float32)]
+    text = _compile(functools.partial(nmc_expert_matmul, act=act,
+                                      out_dtype=jnp.bfloat16),
+                    *args).as_text()
+    calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if "tpu_custom_call" in ln and " custom-call(" in ln]
+    assert len(calls) == 1 and calls[0].startswith("%nmc_expert_matmul.")
+    assert not is_kernel("nmc_matmul")(calls[0])
