@@ -68,13 +68,19 @@ def gqa_apply(p, x, cfg: ModelConfig, *, causal=True, q_offset=0,
     return L.linear(p["wo"], o, nmc_mode=cfg.nmc_mode)
 
 
-def gqa_decode(p, x, cfg: ModelConfig, cache: dict, cache_len) -> tuple:
-    """One-token decode.  cache: {"k","v"}: (B, KV, S_cache, hd); cache_len
-    (B,) absolute lengths.  Sliding-window archs use a RING cache with
-    S_cache == window: slots hold the last `window` tokens (insertion at
-    (len-1) mod S_cache; softmax is permutation-invariant so slot order is
-    irrelevant, and RoPE is applied with absolute positions before insert).
-    Returns (out (B,1,D), new cache)."""
+def gqa_decode(p, x, cfg: ModelConfig, cache: dict, cache_len,
+               layer=None) -> tuple:
+    """One-token decode.  cache: {"k","v"} (and "k_s","v_s" for the int8
+    cache): (B, KV, S_cache, hd) per leaf, or the whole stack (L, B, KV,
+    S_cache, hd) with ``layer`` the index of this layer in it; cache_len
+    (B,) absolute lengths.  The new token's row of each slot is written at
+    (len-1) mod S_cache into the given buffers by :func:`_write_rows`, so a
+    donated stack stays one buffer from the decode program's input to its
+    output; attention then reads this layer's slice.  Sliding-window archs
+    use a RING cache with S_cache == window: slots hold the last `window`
+    tokens (softmax is permutation-invariant so slot order is irrelevant,
+    and RoPE is applied with absolute positions before insert).
+    Returns (out (B,1,D), the cache with the rows written)."""
     b = x.shape[0]
     positions = cache_len[:, None] - 1 + jnp.zeros((b, 1), jnp.int32)
     q = L.linear(p["wq"], x, nmc_mode=cfg.nmc_mode).reshape(
@@ -89,23 +95,24 @@ def gqa_decode(p, x, cfg: ModelConfig, cache: dict, cache_len) -> tuple:
         q = L.apply_rope(q, cos[:, :, None, :], sin[:, :, None, :])
         k = L.apply_rope(k, cos[:, :, None, :], sin[:, :, None, :])
     q = q.transpose(0, 2, 1, 3)
-    s_cache = cache["k"].shape[2]
+    s_cache = cache["k"].shape[-2]
     ring = cfg.window is not None and s_cache <= cfg.window
     idx = (cache_len - 1) % s_cache                         # (B,)
-    new_cache = {}
     if "k_s" in cache:                 # int8 quantized cache
-        kq, ks = _quant_kv(k.transpose(0, 2, 1, 3))
-        vq, vs = _quant_kv(v.transpose(0, 2, 1, 3))
-        new_cache["k"] = _cache_insert(cache["k"], kq, idx)
-        new_cache["v"] = _cache_insert(cache["v"], vq, idx)
-        new_cache["k_s"] = _cache_insert(cache["k_s"], ks, idx)
-        new_cache["v_s"] = _cache_insert(cache["v_s"], vs, idx)
-        kc = _dequant_kv(new_cache["k"], new_cache["k_s"], x.dtype)
-        vc = _dequant_kv(new_cache["v"], new_cache["v_s"], x.dtype)
+        kq, ks = _quant_kv(k[:, 0])
+        vq, vs = _quant_kv(v[:, 0])
+        rows = {"k": kq, "v": vq, "k_s": ks, "v_s": vs}
     else:
-        kc = _cache_insert(cache["k"], k.transpose(0, 2, 1, 3), idx)
-        vc = _cache_insert(cache["v"], v.transpose(0, 2, 1, 3), idx)
-        new_cache = {"k": kc, "v": vc}
+        rows = {"k": k[:, 0], "v": v[:, 0]}
+    # slot b's (KV, d) rows land at [layer, b, :, idx_b, :]
+    cache = {n: _write_rows(c, rows[n], idx, layer, pos_axis=2)
+             for n, c in cache.items()}
+    here = {n: _layer_of(c, layer) for n, c in cache.items()}
+    if "k_s" in cache:
+        kc = _dequant_kv(here["k"], here["k_s"], x.dtype)
+        vc = _dequant_kv(here["v"], here["v_s"], x.dtype)
+    else:
+        kc, vc = here["k"], here["v"]
     if ring:
         # every resident slot is within the window; mask only warmup slots
         o = kops.decode_attention(q, kc, vc,
@@ -115,15 +122,32 @@ def gqa_decode(p, x, cfg: ModelConfig, cache: dict, cache_len) -> tuple:
         o = kops.decode_attention(q, kc, vc, cache_len, window=cfg.window)
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, -1)
     out = L.linear(p["wo"], o, nmc_mode=cfg.nmc_mode)
-    return out, new_cache
+    return out, cache
 
 
-def _cache_insert(cache, new, idx):
-    """cache (B,H,S,d) <- new (B,H,1,d) at per-batch position idx (B,)."""
-    b, h, s, d = cache.shape
-    oh = jax.nn.one_hot(idx, s, dtype=cache.dtype)          # (B, S)
-    return cache * (1 - oh[:, None, :, None]) + \
-        new.astype(cache.dtype) * oh[:, None, :, None]
+def _write_rows(cache, rows, idx, layer=None, *, pos_axis: int):
+    """Write one row per slot into ``cache`` in place: slot ``b``'s row
+    goes to position ``idx[b]`` on the per-layer ``pos_axis`` (the batch
+    axis is 0 there), of layer ``layer`` where ``cache`` is a stack of
+    layers (``layer`` None: one layer's leaf).  ``rows`` holds every
+    per-layer axis but the position one, (B, ..., row).
+
+    A scatter that indexes every axis up to the position one and writes
+    the last axis whole, so its operand needs no transpose and keeps the
+    layout it came in: a donated cache is updated where it lies, and
+    nothing of its size is rewritten or copied."""
+    lead = jnp.indices(rows.shape[:pos_axis], sparse=True)   # b (, h)
+    pos = idx.reshape(idx.shape + (1,) * (pos_axis - 1))
+    at = (*lead, pos) if layer is None else (layer, *lead, pos)
+    return cache.at[at].set(rows.astype(cache.dtype),
+                            mode="promise_in_bounds", unique_indices=True)
+
+
+def _layer_of(cache, layer=None):
+    """Layer ``layer`` of a stacked cache leaf (the leaf itself for None)."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
 
 
 def gqa_cache_init(cfg: ModelConfig, batch: int, max_len: int,
@@ -257,9 +281,16 @@ def mla_prefill(p, x, cfg: ModelConfig, max_len: int) -> tuple:
     return out, cache
 
 
-def mla_decode(p, x, cfg: ModelConfig, cache: dict, cache_len) -> tuple:
+def mla_decode(p, x, cfg: ModelConfig, cache: dict, cache_len,
+               layer=None) -> tuple:
     """Absorbed decode: attention runs in the compressed latent space —
-    per-token cache cost is kv_lora_rank + rope_dim, not H*(nope+v)."""
+    per-token cache cost is kv_lora_rank + rope_dim, not H*(nope+v).
+    cache: {"ckv": (B, S, r), "krope": (B, S, rope)}, or the whole stack
+    (L, B, S, ...) with ``layer`` this layer's index in it.  The new
+    token's latent and rope key of each slot are written at len-1 into the
+    given buffers (:func:`_write_rows`), so a donated stack stays one
+    buffer; attention reads this layer's slice after the write.  Returns
+    (out (B,1,D), the cache with the rows written)."""
     b = x.shape[0]
     h = cfg.n_heads
     r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
@@ -278,12 +309,14 @@ def mla_decode(p, x, cfg: ModelConfig, cache: dict, cache_len) -> tuple:
         L.linear(p["w_krope"], x, nmc_mode=cfg.nmc_mode)[:, :, None, :],
         cos[:, :, None, :], sin[:, :, None, :])[:, :, 0, :]
 
+    # slot b's latent and rope key land at [layer, b, len_b - 1, :]
     idx = cache_len - 1
-    oh = jax.nn.one_hot(idx, cache["ckv"].shape[1], dtype=cache["ckv"].dtype)
-    ckv_c = cache["ckv"] * (1 - oh[..., None]) + \
-        ckv_new.astype(cache["ckv"].dtype) * oh[..., None]
-    krope_c = cache["krope"] * (1 - oh[..., None]) + \
-        krope_new.astype(cache["krope"].dtype) * oh[..., None]
+    cache = {"ckv": _write_rows(cache["ckv"], ckv_new[:, 0], idx, layer,
+                               pos_axis=1),
+             "krope": _write_rows(cache["krope"], krope_new[:, 0], idx,
+                                 layer, pos_axis=1)}
+    ckv_c = _layer_of(cache["ckv"], layer)
+    krope_c = _layer_of(cache["krope"], layer)
 
     # absorb W_uk into q: q_lat (B,H,r) = q_nope @ W_uk(per head)
     w_uk = p["w_uk"]["w"].reshape(r, h, dn) if "w" in p["w_uk"] else (
@@ -306,4 +339,4 @@ def mla_decode(p, x, cfg: ModelConfig, cache: dict, cache_len) -> tuple:
     o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv.astype(jnp.float32))
     o = o.reshape(b, 1, h * dv).astype(x.dtype)
     out = L.linear(p["wo"], o, nmc_mode=cfg.nmc_mode)
-    return out, {"ckv": ckv_c, "krope": krope_c}
+    return out, cache

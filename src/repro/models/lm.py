@@ -99,10 +99,11 @@ def _block_prefill(p, x, cfg: ModelConfig, use_moe: bool, max_len: int):
     return x + y, (cache, rows)
 
 
-def _block_decode(p, x, cfg: ModelConfig, use_moe: bool, cache, cache_len):
+def _block_decode(p, x, cfg: ModelConfig, use_moe: bool, cache, cache_len,
+                  layer=None):
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
     dec = A.mla_decode if cfg.mla else A.gqa_decode
-    y, cache = dec(p["attn"], h, cfg, cache, cache_len)
+    y, cache = dec(p["attn"], h, cfg, cache, cache_len, layer)
     x = x + y
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
     y, rows = _ffn_serve(p, h, cfg, use_moe)
@@ -417,30 +418,42 @@ def cache_batch_axes(cfg: ModelConfig, caches: dict) -> dict:
     raise ValueError(fam)
 
 
+def _decode_stack(layer_params, stack, x, cfg: ModelConfig, use_moe: bool,
+                  cache_len):
+    """Decode through a stack of blocks, the stacked cache carried whole
+    through the layer loop: layer ``i`` writes its rows into ``stack`` at
+    ``i`` in place and reads its slice from there.  Returns (x, stack,
+    the rows routed to each held expert per layer or None)."""
+    def body(carry, lp):
+        h, st, i = carry
+        h, (st, rows) = _block_decode(lp, h, cfg, use_moe, st, cache_len, i)
+        return (h, st, i + 1), rows
+    (x, stack, _), rows = jax.lax.scan(body, (x, stack, jnp.int32(0)),
+                                       layer_params)
+    return x, stack, rows
+
+
 def decode_step(params, tokens, caches: dict, cache_len, cfg: ModelConfig):
     """One decode step.  tokens: (B, 1) int32 (the *new* token ids);
     cache_len: (B,) lengths INCLUDING the new token.  Returns
-    (logits (B, vocab), new caches)."""
+    (logits (B, vocab), new caches).
+
+    For the dense, vlm and moe families each layer writes only the new
+    token's row per slot into the stacked cache, which the layer loop
+    carries whole: with ``caches`` donated, every stacked leaf stays one
+    buffer from the program's input to its output, and the step moves
+    nothing of the cache's size."""
     fam = cfg.family
     x = _embed_in(params, tokens, cfg)
     if fam in ("dense", "vlm", "moe"):
         use_moe = fam == "moe"
+        new = dict(caches)
         if use_moe and cfg.first_dense_layers:
-            def bodyd(h, inp):
-                lp, c = inp
-                h, (nc, _) = _block_decode(lp, h, cfg, False, c, cache_len)
-                return h, nc
-            x, ncd = jax.lax.scan(bodyd, x, (params["dense_layers"],
-                                             caches["dense_layers"]))
-
-        def body(h, inp):
-            lp, c = inp
-            return _block_decode(lp, h, cfg, use_moe, c, cache_len)
-        x, (nc, rows) = jax.lax.scan(body, x, (params["layers"],
-                                               caches["layers"]))
-        new = {"layers": nc}
-        if use_moe and cfg.first_dense_layers:
-            new["dense_layers"] = ncd
+            x, new["dense_layers"], _ = _decode_stack(
+                params["dense_layers"], caches["dense_layers"], x, cfg,
+                False, cache_len)
+        x, new["layers"], rows = _decode_stack(
+            params["layers"], caches["layers"], x, cfg, use_moe, cache_len)
         if rows is not None:
             new[EXPERT_ROWS] = rows
     elif fam == "hybrid":
