@@ -9,18 +9,18 @@ crossover (Caesar beats Carus at small P because of the eCPU bootstrap).
 from __future__ import annotations
 
 from repro.core import energy, programs, timing
-from repro.nmc.pool import BucketedPool, TilePool
+from repro.nmc.pool import ResidentPool
 from benchmarks import paper_data as PD
 
 
 def run(sew: int = 8, verify: bool = False,
-        pool: TilePool | None = None) -> list[dict]:
+        pool: ResidentPool | None = None) -> list[dict]:
     kbs = [programs.build_matmul(sew, p=p, seed=11)
            for p in (8, 16, 32, 64, 128, 256, 512, 1024)]
     if verify:
-        # whole P-sweep through the shape-bucketed tile pool, bit-exact;
-        # the P-sweep's ragged instruction counts share power-of-two buckets
-        res = programs.verify_sweep(kbs, pool or BucketedPool())
+        # whole P-sweep through the resident tile pool, bit-exact; the
+        # P-sweep's ragged instruction counts share power-of-two buckets
+        res = programs.verify_sweep(kbs, pool)
         assert all(all(v.values()) for v in res.values()), res
     rows = []
     for p, kb in zip((8, 16, 32, 64, 128, 256, 512, 1024), kbs):

@@ -8,10 +8,10 @@ the modeled wall-clock of the 8-bit matmul kernel on each target.
 The ``nmc_jit_frontend`` line gates the public one-call path (DESIGN.md
 §7): a traced ``nmc.kernel`` must auto-select its engine and run bit-exact
 vs the tracer's numpy oracle on both engines via both sync and async call
-styles.  All functional sweeps dispatch through one shared shape-bucketed
-:class:`repro.nmc.BucketedPool` — the jit-cache/compile stats it
+styles.  All functional sweeps dispatch through one shared
+:class:`repro.nmc.ResidentPool` — the jit-cache/compile stats it
 reports (and ``table_v.run`` asserts) verify the one-compile-per-bucket
-property of the scheduler, and a :class:`repro.nmc.ResidentPool`
+property of the scheduler, and a second :class:`repro.nmc.ResidentPool`
 re-dispatch demonstrates the residency contract: steady-state dispatches
 move only instruction bytes, never tile memories.  The async
 :class:`repro.nmc.DispatchQueue` section feeds a 2-tile array a
@@ -37,10 +37,10 @@ def main(smoke: bool = False) -> None:
     from repro import nmc
     from repro.core import constants as C
     from repro.core import programs, timing
-    from repro.nmc import BucketedPool, ResidentPool
+    from repro.nmc import ResidentPool
     from benchmarks import fig12, table_v, table_vi, table_viii
 
-    pool = BucketedPool()
+    pool = ResidentPool()
     lines = []
 
     # -- Traced frontend (nmc.jit): the public one-call path ------------------
@@ -84,7 +84,7 @@ def main(smoke: bool = False) -> None:
     # snapshot the pool counters here so the nmc_tile_pool line reports the
     # Table V sweep only (fig12 shares the pool below)
     sweep_stats = (pool.programs_run, pool.dispatches, pool.compiles,
-                   len(pool.shape_keys_compiled), pool.pad_waste,
+                   len(pool.pool.shape_keys_compiled), pool.pool.pad_waste,
                    pool.bytes_moved)
     errs = []
     for r in rows_v:

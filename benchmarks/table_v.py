@@ -8,19 +8,18 @@ The whole sweep rides the public ``repro.nmc`` stack (DESIGN.md §7): the
 kernel builders are traced-frontend kernels (their instruction streams are
 emitted by :mod:`repro.nmc.frontend` lowering, their oracles by the
 tracer's ``alu.*_np`` evaluation), and the functional sweep dispatches
-through a shape-bucketed :class:`repro.nmc.BucketedPool`: all (kernel x
-SEW x engine) instances NOP-pad to power-of-two instruction buckets and
-run as vmapped multi-tile groups — one XLA compile per ``(engine, sew,
-bucket)`` instead of one per kernel instance or exact program shape.
-``run`` asserts the compile bound (compiles <= #buckets) on the pool
-counters, so the CI smoke subset gates the scheduling property, not just
-functional correctness.
+through a :class:`repro.nmc.ResidentPool`: all (kernel x SEW x engine)
+instances NOP-pad to power-of-two instruction buckets and run as batched
+multi-tile groups — one XLA compile per ``(engine, sew, bucket)`` instead
+of one per kernel instance or exact program shape.  ``run`` asserts the
+compile bound (compiles <= #buckets) on the pool counters, so the CI smoke
+subset gates the scheduling property, not just functional correctness.
 """
 
 from __future__ import annotations
 
 from repro.core import energy, programs, timing
-from repro.nmc import BucketedPool, TilePool
+from repro.nmc import ResidentPool
 from benchmarks import paper_data as PD
 
 ALL_SEWS = (8, 16, 32)
@@ -37,21 +36,20 @@ def sweep_buckets(kbs: list) -> set[tuple]:
 def run(verify_functional: bool = True,
         kernels: tuple = programs.TABLE_V_KERNELS,
         sews: tuple = ALL_SEWS,
-        pool: TilePool | None = None) -> list[dict]:
+        pool: ResidentPool | None = None) -> list[dict]:
     kbs = [programs.build(name, sew) for name in kernels for sew in sews]
     func_ok: dict = {}
     if verify_functional:
-        pool = pool if pool is not None else BucketedPool()
+        pool = pool if pool is not None else ResidentPool()
         compiles0 = pool.compiles
         func_ok = programs.verify_sweep(kbs, pool)
         bad = {k: v for k, v in func_ok.items() if not all(v.values())}
         assert not bad, bad
-        if isinstance(pool, BucketedPool):
-            # the scheduling property of DESIGN.md §5: the whole sweep
-            # compiles at most once per (engine, sew, bucket)
-            n_buckets = len(sweep_buckets(kbs))
-            assert pool.compiles - compiles0 <= n_buckets, \
-                (pool.compiles - compiles0, n_buckets)
+        # the scheduling property of DESIGN.md §5: the whole sweep
+        # compiles at most once per (engine, sew, bucket)
+        n_buckets = len(sweep_buckets(kbs))
+        assert pool.compiles - compiles0 <= n_buckets, \
+            (pool.compiles - compiles0, n_buckets)
     rows = []
     for kb in kbs:
         name, sew = kb.name, kb.sew
@@ -83,7 +81,7 @@ def run(verify_functional: bool = True,
 
 
 def main():
-    pool = BucketedPool()
+    pool = ResidentPool()
     rows = run(pool=pool)
     print(f"{'kernel':12s} sew | thrC model/paper | thrK model/paper |"
           f" enC model/paper | enK model/paper")
@@ -106,8 +104,8 @@ def main():
           f"max {100*max(errs):.1f}%")
     print(f"tile pool: {pool.programs_run} programs in {pool.dispatches} "
           f"batched dispatches, {pool.compiles} compiles "
-          f"({len(pool.shape_keys_compiled)} buckets, "
-          f"pad_waste={pool.pad_waste} instr slots, "
+          f"({len(pool.pool.shape_keys_compiled)} buckets, "
+          f"pad_waste={pool.pool.pad_waste} instr slots, "
           f"bytes_moved={pool.bytes_moved})")
     return rows
 

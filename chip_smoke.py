@@ -145,10 +145,10 @@ def check_engines(seed: int):
     kbs = [programs.build(name, sew) for name in programs.TABLE_V_KERNELS
            for sew in SEWS]
     builds = [getattr(kb, e) for kb in kbs for e in ("caesar", "carus")]
-    pallas = nmc.BucketedPool(backend="auto")
-    assert pallas.backend == "pallas"
+    pallas = nmc.ResidentPool()
+    assert pallas.pool.backend == "pallas"
     got = pallas.run_builds(builds)
-    ref = nmc.BucketedPool(backend="scan").run_builds(builds)
+    ref = nmc.ResidentPool(backend="scan").run_builds(builds)
     for eb, g, r in zip(builds, got, ref):
         exp = np.asarray(eb.oracle).reshape(-1)
         assert np.array_equal(np.asarray(g).reshape(-1)[:exp.size], exp), \
@@ -236,8 +236,7 @@ def check_resident_block(seed: int):
     cfg = cb.get("qwen1.5-0.5b", smoke=True).scaled(nmc_mode="w8a8")
     params = quantize_params(lm.init_params(jax.random.PRNGKey(seed), cfg),
                              cfg)
-    pool = nmc.BucketedPool(donate=True, backend="auto")
-    queue = nmc.DispatchQueue(pool=nmc.ResidentPool(pool=pool))
+    queue = nmc.DispatchQueue()
     eng = ServeEngine(cfg, params, n_slots=N_SLOTS, max_len=32,
                       nmc_queue=queue, nmc_tiles=4)
     blk = eng.resident_block(layer=0, rows=N_SLOTS, tiles=4)

@@ -5,7 +5,7 @@ and the Pallas kernels.
    one decorator gives tracing, engine auto-selection, unified-IR
    lowering, pooled scheduling and sync/async dispatch (DESIGN.md §7).
 1. Bucketed multi-tile dispatch — a heterogeneous kernel sweep runs through
-   :class:`repro.nmc.BucketedPool`: instruction streams NOP-pad to
+   :class:`repro.nmc.ResidentPool`: instruction streams NOP-pad to
    power-of-two buckets, so the whole sweep compiles once per
    ``(engine, sew, bucket)`` instead of once per kernel shape.
 2. Resident tile array — :class:`repro.nmc.ResidentPool` keeps tile
@@ -33,7 +33,7 @@ from repro.core import programs, timing
 from repro.kernels import ref
 from repro.kernels.nmc_matmul import nmc_matmul
 from repro.kernels.vrf_alu import make_prog, vrf_alu
-from repro.nmc import BucketedPool, DispatchQueue, ResidentPool
+from repro.nmc import DispatchQueue, ResidentPool
 
 
 def frontend_demo():
@@ -74,7 +74,7 @@ def nmc_scheduler_demo():
     builds = [eb for kb in kbs for eb in (kb.caesar, kb.carus)]
 
     print("bucketed scheduler: heterogeneous sweep, one compile per bucket")
-    pool = BucketedPool()
+    pool = ResidentPool()
     outs = pool.run_builds(builds)
     exact = all((got.reshape(-1)[: eb.oracle.size]
                  == eb.oracle.reshape(-1)).all()
@@ -84,7 +84,7 @@ def nmc_scheduler_demo():
     buckets = {eb.program.bucket_key for eb in builds}
     print(f"  {len(builds)} kernel instances, {len(shapes)} exact shapes -> "
           f"{len(buckets)} buckets, {pool.compiles} compiles, "
-          f"pad_waste={pool.pad_waste} slots, bit-exact={exact}")
+          f"pad_waste={pool.pool.pad_waste} slots, bit-exact={exact}")
 
     print("resident tile array: load once, dispatch many (compute mode)")
     rpool = ResidentPool()

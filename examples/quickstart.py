@@ -18,10 +18,10 @@ tracer evaluates alongside.  This demo:
    planner splits the matmul's output rows over 4 tiles, the wave runs as
    one batched dispatch, a future-of-gathers reassembles the result
    bit-exactly, and the shared-bus timing model reports the wave speedup;
-5. swaps the engine executor under the same kernel (`backend="pallas"`):
-   the bucketed instruction stream runs as one fused `pl.pallas_call`
-   instead of a per-instruction `lax.scan`, bit-exact and timed against
-   the scan reference (DESIGN.md §10).
+5. runs the same kernel on a second runtime with the other executor
+   (`NmcRuntime(backend="pallas")`): the bucketed instruction stream runs
+   as one fused `pl.pallas_call` instead of a per-instruction `lax.scan`,
+   bit-exact and timed against the scan reference (DESIGN.md §10).
 
 Run:  PYTHONPATH=src python examples/quickstart.py   (finishes in ~30 s)
 """
@@ -152,15 +152,18 @@ def main():
 
     print()
     print("=" * 64)
-    print("5. Pallas fast-path backend (backend='pallas', DESIGN.md §10)")
+    print("5. Pallas fast-path backend (NmcRuntime(backend=...), "
+          "DESIGN.md §10)")
     print("=" * 64)
-    # same kernel, same runtime, different executor: the whole bucketed
-    # instruction stream fuses into one pl.pallas_call (interpret mode on
-    # CPU, native kernels on TPU; backend='auto' picks per device)
+    # same kernel, one runtime per executor: the pool picks it once, and
+    # on the Pallas one the whole bucketed instruction stream fuses into
+    # one pl.pallas_call (interpret mode on CPU, native kernels on TPU;
+    # backend='auto', the default, picks per device)
     import time
 
-    ref = np.asarray(matmul8(A, B, backend="scan"))
-    fast = np.asarray(matmul8(A, B, backend="pallas"))
+    rts = {bk: nmc.NmcRuntime(backend=bk) for bk in nmc.BACKENDS}
+    ref = np.asarray(nmc.jit(matmul8.fn, runtime=rts["scan"])(A, B))
+    fast = np.asarray(nmc.jit(matmul8.fn, runtime=rts["pallas"])(A, B))
     assert (fast == ref).all(), "pallas backend diverged from scan"
 
     def best_of(fn, n=3):
@@ -172,11 +175,10 @@ def main():
         return min(t) * 1e6
 
     lk = matmul8.lower(A, B)
-    tile = rt.jit_tile
-    us = {bk: best_of(lambda bk=bk: rt.queue.submit(
-              tile, lk.program, image=lk.mem, out_slice=lk.out_slice,
-              post=lk.post, backend=bk).result())
-          for bk in nmc.BACKENDS}
+    us = {bk: best_of(lambda r=r: r.queue.submit(
+              r.jit_tile, lk.program, image=lk.mem, out_slice=lk.out_slice,
+              post=lk.post).result())
+          for bk, r in rts.items()}
     dev = "CPU interpret mode" if nmc.resolve_backend("auto") == "scan" \
         else "native kernels"
     print("  matmul8 bit-exact scan == pallas: True")

@@ -3,11 +3,10 @@
 Quantizes a model to the W8A8 serving form (per-channel int8 weights,
 dynamic int8 activations, int32 accumulation — the NM-Carus vmacc contract)
 and serves a stream of requests with continuous batching, comparing output
-agreement and weight-memory footprint against the bf16 baseline.  Every
-prefill/decode computation is dispatched as queued work through the async
-:class:`repro.nmc.DispatchQueue` from the one public ``repro.nmc``
-surface (DESIGN.md §5.2/§7), so admission launches overlap on the device
-and the host blocks only at future resolution.
+agreement and weight-memory footprint against the bf16 baseline.  The
+engine calls its jitted prefill/decode programs directly; JAX dispatches
+them asynchronously, so admission launches overlap on the device and the
+host blocks only on the sampled tokens.
 
 The second half demos the resident-block serving path (DESIGN.md §12):
 one decoder layer's quantized weights are DMA'd onto the simulated tile
@@ -47,18 +46,12 @@ def main():
     outs = {}
     for name, (c, p) in {"bf16": (cfg, params),
                          "nmc-w8a8": (qcfg, qparams)}.items():
-        # one dispatch queue per engine so the queued-work counter below is
-        # per-run; without the argument both would share the process-wide
-        # nmc.default_runtime() queue
-        eng = ServeEngine(c, p, n_slots=2, max_len=64,
-                          nmc_queue=nmc.DispatchQueue())
+        eng = ServeEngine(c, p, n_slots=2, max_len=64)
         for i, pr in enumerate(prompts):
             eng.submit(Request(rid=i, prompt=pr, max_new=8))
         done = sorted(eng.run(), key=lambda r: r.rid)
         outs[name] = [r.out for r in done]
-        print(f"{name:9s}: {[o[:6] for o in outs[name]]}  "
-              f"({eng.nmc_queue.calls} prefill/decode computations queued "
-              f"through the async dispatch runtime)")
+        print(f"{name:9s}: {[o[:6] for o in outs[name]]}")
 
     agree = np.mean([np.mean(np.array(a) == np.array(b))
                      for a, b in zip(outs["bf16"], outs["nmc-w8a8"])])

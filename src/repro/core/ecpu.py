@@ -10,8 +10,8 @@ CORE-V X interface.  This module provides:
   *eagerly*, while also being appended to a unified-IR issue trace
   (:mod:`repro.nmc.program` entries), so the exact same kernel can later be
   replayed through the scanned VPU executor — or batched across tiles by
-  :class:`repro.nmc.pool.TilePool` — and costed by :mod:`repro.core.timing`
-  via :meth:`ECpu.program`.
+  :meth:`repro.nmc.pool.ResidentPool.dispatch` — and costed by
+  :mod:`repro.core.timing` via :meth:`ECpu.program`.
 * :func:`assemble` — a minimal assembler for the supported subset (enough to
   write the paper's kernel-driver loops, e.g. the indirect-addressing loop of
   Section III-B1).

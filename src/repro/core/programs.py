@@ -475,16 +475,17 @@ def verify_sweep(kbs: list[KernelBuild], pool=None) -> dict:
     """Batched functional verification of a whole kernel sweep.
 
     Dispatches every (kernel, sew, engine) instance through one
-    :class:`repro.nmc.pool.BucketedPool` (or any pool the caller hands in),
-    so programs sharing an ``(engine, sew, instr-bucket)`` — e.g. the whole
-    elementwise family at one SEW, or ragged matmul P-sweeps — share a
-    single XLA compile and run as one vmapped multi-tile batch.  Returns
+    :class:`repro.nmc.pool.ResidentPool` (a fresh one, or the one the
+    caller hands in), so programs sharing an ``(engine, sew, instr-bucket)``
+    — e.g. the whole elementwise family at one SEW, or ragged matmul
+    P-sweeps — share a single XLA compile and run as one batched
+    multi-tile device call.  Returns
     ``{(name, sew): {engine: ok}}`` — bit-exact against the same oracles as
     the single-instance :func:`verify`.
     """
-    from repro.nmc.pool import BucketedPool
+    from repro.nmc.pool import ResidentPool
 
-    pool = pool if pool is not None else BucketedPool()
+    pool = pool if pool is not None else ResidentPool()
     builds, keys = [], []
     for kb in kbs:
         for engine in ("caesar", "carus"):

@@ -29,12 +29,14 @@ Layers (each usable directly for expert control):
   extract / cost), the two scan-backend tile adapters over the functional
   simulators, and the backend registry (``get_engine(name, backend)``).
 * :mod:`repro.nmc.pallas_engine` — the fused-kernel fast path
-  (DESIGN.md §10): ``backend="pallas"`` lowers whole bucketed waves to one
+  (DESIGN.md §10): a Pallas pool lowers whole bucketed waves to one
   ``pl.pallas_call`` (interpret-mode on CPU), bit-exact vs scan.
-* :mod:`repro.nmc.pool` — the vmapped executors: exact-shape
-  :class:`TilePool`, shape-bucketed :class:`BucketedPool` (one XLA
-  compile per ``(engine, sew, instr-bucket, tile-bucket)``) and the
-  persistently-resident :class:`ResidentPool`.
+* :mod:`repro.nmc.pool` — the batched executors: the compile cache
+  :class:`TilePool` / :class:`BucketedPool` (one XLA compile per
+  ``(engine, sew, instr-bucket, tile-bucket)``; the one place the
+  executor is chosen, ``backend="auto"`` = Pallas on a TPU, scan
+  elsewhere) and the persistently-resident :class:`ResidentPool`, whose
+  ``dispatch`` is the one launch path.
 * :mod:`repro.nmc.runtime` — the async double-buffered
   :class:`DispatchQueue`: futures, shadow-buffer staging, batched launch
   waves (DESIGN.md §5.2).
@@ -51,12 +53,11 @@ Layers (each usable directly for expert control):
 """
 
 from repro.nmc.program import (PROG_DTYPE, Program, caesar_entry, carus_entry,
-                               instr_bucket, nop_entry, stack_programs)
+                               instr_bucket, nop_entry)
 from repro.nmc.engine import (BACKENDS, CaesarTile, CarusTile, Engine,
                               get_engine, implementations, resolve_backend)
 from repro.nmc.pool import BucketedPool, ResidentPool, TilePool, tile_bucket
-from repro.nmc.runtime import (DeviceFuture, DispatchQueue, GatherFuture,
-                               NMCFuture)
+from repro.nmc.runtime import DispatchQueue, GatherFuture, NMCFuture
 from repro.nmc.registry import (NmcRuntime, default_runtime,
                                 set_default_runtime)
 from repro.nmc.frontend import (CompiledKernel, LoweredKernel, LoweringError,
@@ -96,12 +97,12 @@ __all__ = [
     "NmcRuntime", "default_runtime", "set_default_runtime",
     # unified program IR
     "PROG_DTYPE", "Program", "caesar_entry", "carus_entry", "nop_entry",
-    "instr_bucket", "stack_programs",
+    "instr_bucket",
     # engines / backends
     "CaesarTile", "CarusTile", "Engine", "get_engine", "BACKENDS",
     "implementations", "resolve_backend",
     # pools / scheduler
     "TilePool", "BucketedPool", "ResidentPool", "tile_bucket",
     # async dispatch runtime
-    "DispatchQueue", "NMCFuture", "DeviceFuture", "GatherFuture",
+    "DispatchQueue", "NMCFuture", "GatherFuture",
 ]

@@ -42,7 +42,8 @@ layer that rejects such programs before they reach an engine:
   image-defined span, and chained waves are tile-disjoint (no WAR hazard
   across dependent submissions).
 * :func:`assert_wave` / :func:`assert_submittable` — the cheap O(entries)
-  subset the hot scheduler layers (:class:`repro.nmc.pool.BucketedPool`,
+  subset the hot scheduler layers
+  (:meth:`repro.nmc.pool.ResidentPool.dispatch`,
   :class:`repro.nmc.runtime.DispatchQueue`) assert on every dispatch.
 
 ``python -m repro.nmc.check --all`` sweeps every registry kernel x engine

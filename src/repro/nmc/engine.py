@@ -8,8 +8,9 @@ timing/energy models.  The two implementations wrap the existing functional
 simulators — the scans themselves are unchanged and stay bit-exact.
 
 ``scan_fn(sew)`` returns the raw ``(state, arrays) -> state`` callable the
-:class:`repro.nmc.pool.TilePool` maps over tiles with ``jax.vmap``.  The
-bucketed scheduler feeds NOP-padded streams through the same callable:
+:class:`repro.nmc.pool.TilePool` maps over tiles with ``jax.vmap`` (on the
+scan backend).  The bucketed scheduler feeds NOP-padded streams through the
+same callable:
 ``CaesarOp.NOP`` / ``VOp.VNOP`` entries leave the carried state bit-exactly
 unchanged inside both scans, so a padded program's final state equals the
 unpadded one's (property-tested in ``tests/test_nmc_ir.py``).

@@ -1113,16 +1113,6 @@ def _check_engine(engine: str) -> str:
     return engine
 
 
-def _check_backend(backend: str) -> str:
-    """Eager backend-name validation (same discipline as
-    :func:`_check_engine`): ``"auto"``, ``"scan"`` or ``"pallas"``."""
-    from repro.nmc.engine import BACKENDS
-    if backend != "auto" and backend not in BACKENDS:
-        raise ValueError(f"unknown backend {backend!r}: expected 'auto' or "
-                         f"one of {BACKENDS}")
-    return backend
-
-
 def _check_tiles(tiles) -> int:
     try:
         n = int(tiles)
@@ -1191,14 +1181,12 @@ class CompiledKernel:
 
     def __init__(self, fn: Callable, engine: str = "auto", sew: int = 8,
                  runtime: Optional[NmcRuntime] = None, tiles: int = 1,
-                 partition: str = "auto", backend: str = "auto",
-                 check: str = "error", opt: str = "O1",
-                 schedule="uniform"):
+                 partition: str = "auto", check: str = "error",
+                 opt: str = "O1", schedule="uniform"):
         # kwargs validate eagerly: a typo'd engine string or an impossible
         # tile count must fail at decoration time with a named cause, not
         # as a deep-stack assertion at first call
         _check_engine(engine)
-        _check_backend(backend)
         _check_checkmode(check)
         _check_opt(opt)
         _check_schedule(schedule)
@@ -1216,7 +1204,6 @@ class CompiledKernel:
         self.sew = sew
         self.tiles = tiles
         self.partition = partition
-        self.backend = backend
         self.check = check
         self.opt = opt
         self.schedule = schedule
@@ -1350,31 +1337,17 @@ class CompiledKernel:
     # -- execution -----------------------------------------------------------
     def __call__(self, *args, engine: Optional[str] = None,
                  tiles: Optional[int] = None,
-                 backend: Optional[str] = None,
                  opt: Optional[str] = None,
                  schedule=None) -> np.ndarray:
         """Synchronous call: submit and resolve immediately.  Shares the
         async path's tiles and jit cache, so sync and async are bit-exact
         by construction and device state stays bounded (one resident
         buffer per runtime tile, re-installed per call)."""
-        return self.call_async(*args, engine=engine, tiles=tiles,
-                               backend=backend, opt=opt,
+        return self.call_async(*args, engine=engine, tiles=tiles, opt=opt,
                                schedule=schedule).result()
-
-    def resolve_backend(self, backend: Optional[str] = None) -> str:
-        """The executor this call will use: per-call override > kernel
-        default > runtime default; ``"auto"`` follows the runtime, whose
-        own ``"auto"`` picks Pallas on TPU/GPU and scan on CPU."""
-        from repro.nmc.engine import resolve_backend
-        bk = self.backend if backend is None else _check_backend(backend)
-        if bk == "auto":
-            rt_bk = getattr(self.runtime, "backend", None)
-            return rt_bk if rt_bk is not None else resolve_backend("auto")
-        return bk
 
     def call_async(self, *args, engine: Optional[str] = None,
                    tiles: Optional[int] = None,
-                   backend: Optional[str] = None,
                    opt: Optional[str] = None,
                    schedule=None):
         """Submit through the runtime's DispatchQueue; returns the future
@@ -1392,31 +1365,29 @@ class CompiledKernel:
         single-tile path by construction).  Per-tile FIFO order keeps any
         number of in-flight futures correct either way.
 
+        The executor is the runtime pool's (``NmcRuntime(backend=)``).
         The call is one ``frontend.call`` span with a fresh call id, which
         its work items keep for their dispatch spans (DESIGN.md §15)."""
         with span("frontend.call", call=new_call()):
             n = self.tiles if tiles is None else _check_tiles(tiles)
-            bk = self.resolve_backend(backend)
             rt = self.runtime
             if n == 1:
                 lk = self.lower(*args, engine=engine, opt=opt)
                 return rt.queue.submit(rt.jit_tile, lk.program, image=lk.mem,
-                                       out_slice=lk.out_slice, post=lk.post,
-                                       backend=bk)
+                                       out_slice=lk.out_slice, post=lk.post)
             from repro.nmc.runtime import GatherFuture
             pplan, lks = self.lower_wave(*args, engine=engine, tiles=n,
                                          opt=opt, schedule=schedule)
             futs = [rt.queue.submit(tile, lk.program, image=lk.mem,
-                                    out_slice=lk.out_slice, post=lk.post,
-                                    backend=bk)
+                                    out_slice=lk.out_slice, post=lk.post)
                     for tile, lk in zip(rt.jit_tiles(len(lks)), lks)]
             return GatherFuture(futs, pplan.gather)
 
 
 def jit(fn: Optional[Callable] = None, *, engine: str = "auto", sew: int = 8,
         runtime: Optional[NmcRuntime] = None, tiles: int = 1,
-        partition: str = "auto", backend: str = "auto",
-        check: str = "error", opt: str = "O1", schedule="uniform"):
+        partition: str = "auto", check: str = "error", opt: str = "O1",
+        schedule="uniform"):
     """Compile a traced kernel function into a :class:`CompiledKernel`.
 
     ``engine`` is ``"auto"`` (NM-Caesar when bus-expressible, NM-Carus
@@ -1425,11 +1396,10 @@ def jit(fn: Optional[Callable] = None, *, engine: str = "auto", sew: int = 8,
     op.  ``sew`` is the element width (8/16/32).  ``tiles`` shards every
     call across that many tiles through the partitioning planner
     (DESIGN.md §9) — ``partition`` picks the split strategy (``"auto"``,
-    ``"rows"``, ``"axis"``).  ``backend`` picks the executor
-    (DESIGN.md §10): ``"scan"`` (reference interpreters), ``"pallas"``
-    (fused kernels), or ``"auto"`` (Pallas on TPU/GPU, scan on CPU).
-    ``check`` runs the static verifier (:mod:`repro.nmc.check`,
-    DESIGN.md §11) on every lowered program: ``"error"`` (default —
+    ``"rows"``, ``"axis"``).  The executor (scan or Pallas, DESIGN.md
+    §10) is the runtime's: ``NmcRuntime(backend=)`` picks it.  ``check``
+    runs the static verifier (:mod:`repro.nmc.check`, DESIGN.md §11) on
+    every lowered program: ``"error"`` (default —
     raise :class:`repro.nmc.check.VerificationError` on any error-severity
     diagnostic), ``"warn"`` (surface findings as Python warnings) or
     ``"off"``.  ``opt`` runs the analysis-driven IR optimizer
@@ -1448,12 +1418,11 @@ def jit(fn: Optional[Callable] = None, *, engine: str = "auto", sew: int = 8,
     if fn is None:
         return lambda f: CompiledKernel(f, engine=engine, sew=sew,
                                         runtime=runtime, tiles=tiles,
-                                        partition=partition, backend=backend,
-                                        check=check, opt=opt,
-                                        schedule=schedule)
+                                        partition=partition, check=check,
+                                        opt=opt, schedule=schedule)
     return CompiledKernel(fn, engine=engine, sew=sew, runtime=runtime,
-                          tiles=tiles, partition=partition, backend=backend,
-                          check=check, opt=opt, schedule=schedule)
+                          tiles=tiles, partition=partition, check=check,
+                          opt=opt, schedule=schedule)
 
 
 def kernel(fn: Optional[Callable] = None, **options):

@@ -38,7 +38,8 @@ Kernel layout (one shape for both engines):
   on ``op`` — no branches, one state write per instruction.
 * **SEW specialization.**  ``sew`` is static: the
   :class:`repro.nmc.pool.BucketedPool` cache key ``(engine, sew,
-  instr-bucket, tile-bucket, backend)`` maps one-to-one onto kernels.
+  instr-bucket, tile-bucket)`` of a Pallas pool maps one-to-one onto
+  kernels.
   Each kernel is named ``caesar_sew<SEW>`` or ``carus_sew<SEW>``: the
   name the kernel's custom call carries in the compiled HLO and in a
   device trace.
@@ -371,16 +372,16 @@ class _PallasMixin:
         self.interpret = interpret_mode()
         self._fns: dict = {}
 
-    def batched_fn(self, sew: int, n_tiles: int, donate: bool = False):
+    def batched_fn(self, sew: int, n_tiles: int):
         """``(batch_state[T, ...], batch_arrays[T, n]) -> batch_state`` —
-        the pool-facing executor (one fused pallas_call per wave); the
-        drop-in replacement for ``jit(vmap(scan_fn(sew)))``."""
-        key = (sew, n_tiles, donate)
+        the pool-facing executor (one fused pallas_call per wave), jitted;
+        the pool traces the function under the jit (``__wrapped__``) in
+        place of ``vmap(scan_fn(sew))``."""
+        key = (sew, n_tiles)
         fn = self._fns.get(key)
         if fn is None:
             fn = jax.jit(functools.partial(type(self)._wave, sew=sew,
-                                           interpret=self.interpret),
-                         donate_argnums=(0,) if donate else ())
+                                           interpret=self.interpret))
             self._fns[key] = fn
         return fn
 

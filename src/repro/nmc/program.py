@@ -16,11 +16,12 @@ special-cased both.  This module replaces the split with one structured-array
 * :meth:`Program.lower` producing exactly the dict-of-arrays the scan-based
   engines consume, keyed by the engine's own field names; and
 * :attr:`Program.shape_key` — the ``(engine, sew, n_instr)`` tuple the
-  :class:`repro.nmc.pool.TilePool` uses as its jit-cache key.
+  :class:`repro.nmc.pool.TilePool` uses (with the tile count) as its
+  jit-cache key.
 
 The IR is deliberately *flat* (a numpy structured array, no objects) so a
-batch of T same-shape programs stacks into ``[T, n_instr]`` arrays and runs
-under ``jax.vmap`` unchanged.
+batch of T same-shape programs packs into one ``[F, T, n_instr]`` array
+(:func:`pack_programs`) and runs under ``jax.vmap`` unchanged.
 """
 
 from __future__ import annotations
@@ -237,16 +238,6 @@ class Program:
 
 def _dtype_of(x) -> np.dtype | None:
     return getattr(x, "dtype", None)
-
-
-def stack_programs(programs: list[Program]) -> dict[str, np.ndarray]:
-    """Stack same-shape programs into [T, n_instr] engine-field arrays."""
-    key = programs[0].shape_key
-    assert all(p.shape_key == key for p in programs), \
-        [p.shape_key for p in programs]
-    fields = programs[0].field_map()
-    return {eng_name: np.stack([p.entries[ir_name] for p in programs])
-            for eng_name, ir_name in fields}
 
 
 def pack_programs(programs: list[Program]) -> np.ndarray:

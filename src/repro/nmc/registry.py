@@ -12,12 +12,13 @@ Two registries back :mod:`repro.nmc.frontend` (DESIGN.md §7):
 * **Runtime registry** — the process-wide :class:`NmcRuntime` every
   :class:`repro.nmc.frontend.CompiledKernel` dispatches through by default:
   one shared :class:`repro.nmc.pool.BucketedPool` jit cache (one XLA
-  compile per ``(engine, sew, instr-bucket, tile-bucket)``) under a
-  :class:`repro.nmc.pool.ResidentPool` and its
-  :class:`repro.nmc.runtime.DispatchQueue`.  Every kernel call — sync or
-  async — submits to the queue on the shared ``jit_tile`` (a synchronous
-  call simply resolves its future immediately), so both call styles share
-  one code path, one jit cache, and are bit-exact equal by construction.
+  compile per ``(engine, sew, instr-bucket, tile-bucket)``, on the one
+  executor the pool picked) under a :class:`repro.nmc.pool.ResidentPool`
+  and its :class:`repro.nmc.runtime.DispatchQueue`.  Every kernel call —
+  sync or async — submits to the queue on the shared ``jit_tile`` (a
+  synchronous call simply resolves its future immediately), so both call
+  styles share one code path, one jit cache, and are bit-exact equal by
+  construction.
 """
 
 from __future__ import annotations
@@ -83,7 +84,9 @@ class NmcRuntime:
     Holds the three scheduler layers as one object so every kernel compiled
     by :func:`repro.nmc.frontend.jit` reuses one jit cache:
 
-    * ``bucketed`` — the shape-bucketed compile cache (donated state),
+    * ``bucketed`` — the shape-bucketed compile cache, holding the
+      executor (``backend``: ``"auto"`` is Pallas on a TPU, scan
+      elsewhere; a comparison of executors builds a second runtime),
     * ``resident`` — the device-resident tile array under the queue,
     * ``queue``    — the double-buffered dispatch queue all kernel calls
       submit to (sync calls resolve their future immediately; async ones
@@ -94,15 +97,18 @@ class NmcRuntime:
     one shard per tile.
     """
 
-    def __init__(self, mode: str = "overlapped", backend: str = "auto"):
-        from repro.nmc.engine import resolve_backend
+    def __init__(self, backend: str = "auto"):
         from repro.nmc.pool import BucketedPool, ResidentPool
         from repro.nmc.runtime import DispatchQueue
 
-        self.backend = resolve_backend(backend)
-        self.bucketed = BucketedPool(donate=True, backend=self.backend)
+        self.bucketed = BucketedPool(backend=backend)
         self.resident = ResidentPool(pool=self.bucketed)
-        self.queue = DispatchQueue(pool=self.resident, mode=mode)
+        self.queue = DispatchQueue(pool=self.resident)
+
+    @property
+    def backend(self) -> str:
+        """The executor every kernel call runs on (the pool's)."""
+        return self.bucketed.backend
 
     @classmethod
     def for_queue(cls, queue) -> "NmcRuntime":
@@ -110,13 +116,11 @@ class NmcRuntime:
         the pools under it) as a runtime, so compiled kernels can join a
         caller-owned dispatch discipline instead of the process default —
         e.g. a :class:`repro.serve.engine.ServeEngine` given a private
-        queue routes its tile-array projections through the same queue it
-        uses for prefill/decode work."""
+        queue routes its tile-array projections through that queue."""
         rt = cls.__new__(cls)
         rt.bucketed = queue.pool.pool
         rt.resident = queue.pool
         rt.queue = queue
-        rt.backend = rt.bucketed.backend
         return rt
 
     def jit_tiles(self, n: int) -> tuple:

@@ -22,8 +22,9 @@ that duality executable on top of :class:`repro.nmc.pool.ResidentPool`:
   chained programs on one tile land in consecutive waves.
 * :meth:`NMCFuture.result` is the only synchronization point: it
   ``jax.block_until_ready``\\ s the captured final state, extracts the output
-  slice (memory-mode read, counted in the pool's ``bytes_moved``), applies
-  the build's host-side ``post`` stage, and caches the result.
+  slice (memory-mode read, :meth:`ResidentPool.extract`, counted in the
+  pool's ``bytes_moved``), applies the build's host-side ``post`` stage, and
+  caches the result.
 
 Each stage is one span (:mod:`repro.spans`, DESIGN.md §15):
 ``dispatch.submit``, ``dispatch.stage``, ``dispatch.wait`` and
@@ -31,22 +32,11 @@ Each stage is one span (:mod:`repro.spans`, DESIGN.md §15):
 keeps the span ids open at its submit (the ``nmc.jit`` call id), so its
 later spans carry them too.
 
-Two schedulers are pluggable via ``mode``:
-
-* ``"overlapped"`` (default) — eager staging + lazy batched waves: the
-  double-buffered pipeline whose modeled cost is
-  ``timing.dispatch_cycles(stages, mode="overlapped")`` (max(dma, compute)
-  per steady-state stage instead of their sum).
-* ``"inorder"`` — the serial reference: each submit blocks on the tile's
-  previous work before staging, then launches a single-item wave.  Results
-  are bit-exact equal between the two modes (and to synchronous
-  ``ResidentPool.dispatch``); only the overlap counters differ.
-
-``submit_call(fn, *args)`` is the generic device-work flavor of the same
-contract: it launches any JAX computation (already asynchronously dispatched
-by the runtime) and wraps the result pytree in a :class:`DeviceFuture`, so
-host-side consumers (e.g. :class:`repro.serve.engine.ServeEngine` admission)
-adopt the same block-only-at-resolution discipline.
+The queue has one scheduler — eager staging and lazy batched waves, the
+double-buffered pipeline whose modeled cost is
+``timing.dispatch_cycles(stages, mode="overlapped")`` (max(dma, compute)
+per steady-state stage instead of their sum).  Results are bit-exact equal
+to synchronous ``ResidentPool.dispatch``; the executor is the pool's.
 """
 
 from __future__ import annotations
@@ -59,11 +49,9 @@ import jax
 import numpy as np
 
 from repro.nmc.engine import get_engine
-from repro.nmc.pool import WORD_BYTES, ResidentPool
+from repro.nmc.pool import ResidentPool
 from repro.nmc.program import Program
 from repro.spans import current_ids, span
-
-SCHEDULERS = ("inorder", "overlapped")
 
 
 class NMCFuture:
@@ -120,9 +108,9 @@ class NMCFuture:
             final = self.state()
             if self.out_slice is not None:
                 with span("dispatch.extract", **self.span_ids):
-                    elems = get_engine(self.program.engine).extract(
-                        final, self.out_slice, self.program.sew)
-                    self.queue._account_store(self.out_slice)
+                    elems = self.queue.pool.extract(
+                        self.program.engine, final, self.out_slice,
+                        self.program.sew)
                     self._out = self.post(elems) if self.post else elems
             self._resolved = True
             self.queue.resolved += 1
@@ -165,28 +153,6 @@ class GatherFuture:
         return self._out
 
 
-class DeviceFuture:
-    """Future over an already-launched JAX computation (async dispatch):
-    ``result()`` blocks until the value pytree is ready."""
-
-    def __init__(self, value):
-        self._value = value
-        self._ready = False
-
-    @property
-    def value(self):
-        """The launched result pytree *without* blocking — JAX arrays are
-        themselves futures, so consumers that only force part of the tree
-        (e.g. argmax on logits) can keep the rest in flight."""
-        return self._value
-
-    def result(self):
-        if not self._ready:
-            jax.block_until_ready(self._value)
-            self._ready = True
-        return self._value
-
-
 @dataclasses.dataclass
 class _WorkItem:
     tile: object
@@ -196,7 +162,6 @@ class _WorkItem:
     engine: str
     future: NMCFuture
     prev: Optional[NMCFuture]       # preceding future on this tile, if any
-    backend: Optional[str] = None   # executor override for this item's wave
     patch: object = None            # (word_start, words) spans applied onto
                                     # the resident state at launch — the
                                     # steady-state serving path (weights
@@ -209,19 +174,15 @@ class DispatchQueue:
     Counters (asserted by tests/benchmarks):
 
     * ``submitted`` / ``launched`` / ``resolved`` — work-item lifecycle.
-    * ``waves`` — batched launch rounds (>= 1 ``ResidentPool.dispatch``
-      each; one per distinct bucket in the wave).
+    * ``waves`` — batched launch rounds (one ``ResidentPool.dispatch``
+      each, which makes one device call per bucket group).
     * ``staged_while_busy`` — images staged into a tile's shadow buffer
       while its previous program was still unresolved: the double-buffering
-      overlap events.  Always 0 under the ``inorder`` scheduler.
-    * ``calls`` — generic device computations launched via ``submit_call``.
+      overlap events.
     """
 
-    def __init__(self, pool: ResidentPool | None = None,
-                 mode: str = "overlapped"):
-        assert mode in SCHEDULERS, mode
+    def __init__(self, pool: ResidentPool | None = None):
         self.pool = pool if pool is not None else ResidentPool()
-        self.mode = mode
         self._queued: list[_WorkItem] = []
         self._last: dict[object, NMCFuture] = {}  # tile -> FIFO tail
         self._outstanding: dict[int, NMCFuture] = {}   # pruned at result()
@@ -233,13 +194,11 @@ class DispatchQueue:
         self.waves = 0
         self.mixed_engine_waves = 0     # waves mixing Caesar+Carus shards
         self.staged_while_busy = 0
-        self.calls = 0
 
     # -- submission ----------------------------------------------------------
     def submit(self, tile, program: Program, image=None,
                out_slice: Optional[tuple[int, int]] = None,
                post: Optional[Callable] = None,
-               backend: Optional[str] = None,
                patch: Optional[list] = None) -> NMCFuture:
         """Queue one work item; returns its future immediately.
 
@@ -250,9 +209,7 @@ class DispatchQueue:
         otherwise when the item's launch wave is assembled (right after the
         previous wave dispatched, so the transfer overlaps the in-flight
         compute either way).  Without an image the program chains against
-        the tile's current resident state.  ``backend`` (optional) pins the
-        item to an executor ("scan"/"pallas"); waves group per backend at
-        launch, default follows the pool.
+        the tile's current resident state.
 
         ``patch`` (optional) is a list of ``(word_start, words)`` spans
         applied onto the tile's resident state when the item launches
@@ -265,12 +222,9 @@ class DispatchQueue:
             # (DESIGN.md §11): full verification belongs at lowering time
             assert_submittable(program)
             prev = self._last.get(tile)
-            if image is not None and self.mode == "inorder" \
-                    and prev is not None and not prev.done:
-                prev.state()            # serial DMA: wait before staging
             fut = NMCFuture(self, tile, program, out_slice, post)
             item = _WorkItem(tile, program, image, None, program.engine, fut,
-                             prev, backend, patch)
+                             prev, patch)
             # depth-2 double buffering: at most one staged shadow buffer
             # per tile ahead of the resident (possibly computing) state
             if image is not None and not self._staged_pending.get(tile):
@@ -280,8 +234,6 @@ class DispatchQueue:
             fut._seq = next(self._seq)
             self._outstanding[fut._seq] = fut
             self.submitted += 1
-            if self.mode == "inorder":
-                self.flush()
             return fut
 
     def _stage(self, item: _WorkItem) -> None:
@@ -295,13 +247,6 @@ class DispatchQueue:
             self._staged_pending.get(item.tile, 0) + 1
         if item.prev is not None and not item.prev.done:
             self.staged_while_busy += 1
-
-    def submit_call(self, fn: Callable, *args, **kwargs) -> DeviceFuture:
-        """Launch a generic JAX computation as queued device work (the
-        runtime's async dispatch does the overlapping); block only at
-        ``result()``."""
-        self.calls += 1
-        return DeviceFuture(fn(*args, **kwargs))
 
     # -- launching -----------------------------------------------------------
     def flush(self) -> None:
@@ -332,12 +277,7 @@ class DispatchQueue:
                     # state (after any install, so patch words win over
                     # image words)
                     self.pool.patch(it.tile, it.patch)
-            by_backend: dict[str, list[_WorkItem]] = {}
-            for it in wave:
-                by_backend.setdefault(it.backend, []).append(it)
-            for backend, items in by_backend.items():
-                self.pool.dispatch([(it.tile, it.program) for it in items],
-                                   backend=backend)
+            self.pool.dispatch([(it.tile, it.program) for it in wave])
             for it in wave:         # capture the wave's final state per item
                 it.future._final = self.pool.state(it.tile)
         self.launched += len(wave)
@@ -378,10 +318,3 @@ class DispatchQueue:
             futs.append(self.submit(tile, eb.program, image=eb.mem,
                                     out_slice=eb.out_slice, post=eb.post))
         return [f.result() for f in futs]
-
-    # -- accounting ----------------------------------------------------------
-    def _account_store(self, out_slice: tuple[int, int]) -> None:
-        # mirrors ResidentPool.store: word-granular (n_words * 4), so
-        # sub-word element tails at SEW 8/16 cost their whole last word
-        self.pool.stores += 1
-        self.pool.bytes_moved += int(out_slice[1]) * WORD_BYTES

@@ -10,20 +10,18 @@ updated in place (memory-mode/compute-mode duality), and with
 batch of S slots; finished sequences free their slot, queued requests are
 prefilled into it (prefill at batch 1 here; production would chunk).
 
-All device work — prefill admission and decode steps — is dispatched as
-queued work through an :class:`repro.nmc.DispatchQueue` from the curated
-``repro.nmc`` public surface (with ``nmc_mode='w8a8'`` those are exactly
-the int8 NMC projections): the queue launches the computations
-asynchronously and the engine blocks only at future resolution, so a
-batch of admissions issues all its prefills before the first slot
-insert (DESIGN.md §5.2).  By default the engine joins the shared
-:func:`repro.nmc.default_runtime` queue, so serving traffic and
-``nmc.jit`` kernel calls drain through one dispatch discipline.
+The engine calls its jitted prefill and decode programs directly.  JAX
+dispatches them asynchronously, and the engine forces only what it reads
+(the sampled tokens), so a batch of admissions issues all its prefills
+before the first slot insert.
 
 W8A8 projections offloaded to the simulated tile array
-(:meth:`ServeEngine.nmc_project`) shard across ``nmc_tiles`` tiles via
-the partitioning planner (DESIGN.md §9) — the same planner, queue and
-bucketed jit cache every ``nmc.jit(tiles=N)`` kernel uses.
+(:meth:`ServeEngine.nmc_project`, :meth:`ServeEngine.resident_block`)
+go through an :class:`repro.nmc.DispatchQueue` (``nmc_queue``; by default
+the shared :func:`repro.nmc.default_runtime` queue) and shard across
+``nmc_tiles`` tiles via the partitioning planner (DESIGN.md §9) — the same
+planner, queue and bucketed jit cache every ``nmc.jit(tiles=N)`` kernel
+uses.
 """
 
 from __future__ import annotations
@@ -92,8 +90,7 @@ class ServeEngine:
         # W8A8 projections offloaded to the NMC tile array shard across
         # this many tiles via the partitioning planner (DESIGN.md §9);
         # they dispatch through THIS engine's queue (for_queue wraps a
-        # caller-owned queue as a kernel runtime), so serving traffic and
-        # projection waves share one dispatch discipline and jit cache
+        # caller-owned queue as a kernel runtime)
         self.nmc_tiles = int(nmc_tiles)
         if self.nmc_tiles < 1:
             raise ValueError(f"nmc_tiles must be >= 1, got {nmc_tiles!r}")
@@ -138,9 +135,8 @@ class ServeEngine:
         jitted bf16/int8 JAX decode loop stands in for the host CPU, and
         projections routed here run on the simulated tile array through
         the same planner and bucketed jit cache as ``nmc.jit`` kernels —
-        submitted to *this engine's* dispatch queue (``nmc_queue``), so
-        prefill/decode work and projection waves drain through one
-        discipline.  Demo-scale by design — one projection per call,
+        submitted to *this engine's* dispatch queue (``nmc_queue``).
+        Demo-scale by design — one projection per call,
         shapes bounded by a tile's SRAM macro."""
         x8 = np.asarray(x8, np.int8)
         w8 = np.asarray(w8, np.int8)
@@ -185,8 +181,7 @@ class ServeEngine:
 
         ``rows`` is the per-call token-row count (defaults to this engine's
         slot count); ``tiles`` the per-projection shard width (defaults to
-        ``nmc_tiles``).  Dispatches through this engine's queue, so block
-        waves and serving traffic share one discipline."""
+        ``nmc_tiles``).  Dispatches through this engine's queue."""
         from repro.serve.block import ResidentBlock
         if self.cfg.family not in ("dense", "vlm"):
             raise ValueError(
@@ -206,11 +201,10 @@ class ServeEngine:
 
     def _admit(self):
         # two-phase admission: launch a prefill for every (free slot, queued
-        # request) pair as queued device work first — the dispatch queue's
-        # async launches overlap on the device — then resolve the futures
-        # and write each cache into its slot.  Bit-identical to admitting
-        # one slot at a time (prefills are independent); only the overlap
-        # differs.
+        # request) pair first — JAX dispatches them asynchronously, so they
+        # overlap on the device — then write each cache into its slot.
+        # Bit-identical to admitting one slot at a time (prefills are
+        # independent); only the overlap differs.
         launches = []
         for s in range(self.n_slots):
             if self.max_prefills is not None \
@@ -218,14 +212,12 @@ class ServeEngine:
                 break
             if self.slot_req[s] is None and self.queue:
                 req = self.queue.pop(0)
-                fut = self.nmc_queue.submit_call(
-                    self.prefill, self.params,
-                    {"tokens": jnp.asarray(req.prompt[None])})
-                launches.append((s, req, fut))
-        for s, req, fut in launches:
-            # .value, not .result(): the arrays are their own futures — the
-            # argmax below forces logits while the slot insert stays queued
-            logits, caches1 = fut.value
+                out = self.prefill(self.params,
+                                   {"tokens": jnp.asarray(req.prompt[None])})
+                launches.append((s, req, out))
+        for s, req, (logits, caches1) in launches:
+            # the arrays are their own futures: the argmax below forces
+            # logits while the slot insert stays queued
             if lm.EXPERT_ROWS in caches1:
                 self.expert_rows.append((len(req.prompt),
                                          caches1[lm.EXPERT_ROWS]))
@@ -255,12 +247,10 @@ class ServeEngine:
             return False
         toks = jnp.asarray(self.slot_last_tok[:, None])
         clen = jnp.asarray(self.slot_len)
-        # decode is queued NMC work too: launched async; only the sampled
-        # tokens are forced below, the cache update stays in flight under
-        # the host-side slot bookkeeping
-        fut = self.nmc_queue.submit_call(self.decode, self.params, toks,
-                                         self.caches, clen)
-        logits, self.caches = fut.value
+        # launched async: only the sampled tokens are forced below, the
+        # cache update stays in flight under the host-side slot bookkeeping
+        logits, self.caches = self.decode(self.params, toks, self.caches,
+                                          clen)
         if lm.EXPERT_ROWS in self.caches:
             self.expert_rows.append((self.n_slots,
                                      self.caches[lm.EXPERT_ROWS]))

@@ -352,7 +352,7 @@ def test_backend_parity_sync_and_async_dispatch(sew):
                 for t in range(len(progs))]
         # async: same wave through the double-buffered queue
         q = DispatchQueue(pool=ResidentPool(backend=backend))
-        futs = [q.submit(("t", t), p, image=img, backend=backend)
+        futs = [q.submit(("t", t), p, image=img)
                 for t, (p, img) in enumerate(zip(progs, images))]
         asyn = [np.asarray(f.state()) for f in futs]
         for got_s, got_a, exp, p in zip(sync, asyn, oracles, progs):

@@ -23,6 +23,8 @@ RNG = np.random.default_rng(42)
 
 # one shared runtime for the module: sync + async share a jit cache
 _RT = nmc.NmcRuntime()
+# one runtime per executor, for the comparisons between them
+_RT_BY_BACKEND = {bk: nmc.NmcRuntime(backend=bk) for bk in nmc.BACKENDS}
 
 
 def _rand(n, sew, shape=None):
@@ -325,41 +327,33 @@ def test_jit_kwargs_validate_eagerly():
 
 
 def test_backend_kwarg_validates_eagerly():
-    """An unknown backend must raise a ValueError naming the valid set at
-    decoration time, and identically for per-call overrides."""
-    def body(t, x):
-        t.store(t.load(x) + 1)
-
+    """An unknown backend must raise a ValueError naming the valid set
+    where the executor is chosen: when the runtime or pool is built."""
     with pytest.raises(ValueError, match="backend 'bogus'.*scan.*pallas"):
-        nmc.jit(body, backend="bogus")
+        nmc.NmcRuntime(backend="bogus")
+    with pytest.raises(ValueError, match="backend 'bogus'"):
+        nmc.ResidentPool(backend="bogus")
     with pytest.raises(ValueError, match="backend"):
-        nmc.jit(body, backend=8)
-    k = nmc.jit(body, runtime=_RT)
-    with pytest.raises(ValueError, match="backend 'bogus'"):
-        k(_rand(16, 8), backend="bogus")
-    with pytest.raises(ValueError, match="backend 'bogus'"):
-        k.call_async(_rand(16, 8), backend="bogus")
-    # valid spellings construct; 'auto' resolves through the runtime
-    assert nmc.jit(body, backend="pallas").backend == "pallas"
-    assert nmc.jit(body, backend="auto", runtime=_RT).resolve_backend() \
-        in nmc.BACKENDS
+        nmc.NmcRuntime(backend=8)
+    # valid spellings construct; 'auto' resolves to a registered backend
+    assert nmc.NmcRuntime(backend="pallas").backend == "pallas"
+    assert _RT.backend in nmc.BACKENDS
 
 
 @pytest.mark.parametrize("sew", [8, 16, 32])
 def test_backend_pallas_bit_exact_vs_scan(sew):
-    """The same traced kernel through backend='pallas' must equal the
-    scan reference bit-for-bit — sync call and per-call override."""
+    """The same traced kernel on a Pallas runtime must equal the scan
+    runtime bit-for-bit — sync call and async future."""
     x, y = _rand(128, sew), _rand(128, sew)
 
-    @nmc.jit(sew=sew, runtime=_RT)
-    def k(t, a, b):
+    def body(t, a, b):
         t.store((t.load(a, bank=0) + t.load(b)) * t.load(a, bank=0))
 
-    ref = np.asarray(k(x, y, backend="scan"))
-    via_kwarg = np.asarray(k(x, y, backend="pallas"))
-    assert (via_kwarg == ref).all()
-    kp = nmc.jit(k.fn, sew=sew, runtime=_RT, backend="pallas")
-    assert (np.asarray(kp(x, y)) == ref).all()
+    ks = {bk: nmc.jit(body, sew=sew, runtime=rt)
+          for bk, rt in _RT_BY_BACKEND.items()}
+    ref = np.asarray(ks["scan"](x, y))
+    assert (np.asarray(ks["pallas"](x, y)) == ref).all()
+    assert (np.asarray(ks["pallas"].call_async(x, y).result()) == ref).all()
 
 
 def test_mac_rejects_scalar_accumulator():

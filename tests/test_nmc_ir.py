@@ -2,9 +2,8 @@
 
 Covers the IR and scheduler contracts:
 * IR encode/decode round-trips losslessly for both engine formats,
-* the vmapped multi-tile pool is bit-exact vs. the single-instance path for
+* the batched multi-tile pool is bit-exact vs. the single-instance path for
   every kernel in programs.ALL_KERNELS x SEW in {8, 16, 32},
-* the exact-shape pool compiles once per (engine, sew, n_instr) shape,
 * NOP padding is bit-exact and zero-cost on both engines (the bucketed
   scheduler's filler),
 * the bucketed pool compiles once per (engine, sew, instr-bucket,
@@ -19,9 +18,8 @@ import pytest
 from repro.core import ecpu, energy, isa, programs
 from repro.core import timing
 from repro.core.isa import CaesarOp, VOp
-from repro.nmc import (BucketedPool, Program, ResidentPool, TilePool,
-                       caesar_entry, carus_entry, instr_bucket, nop_entry,
-                       tile_bucket)
+from repro.nmc import (Program, ResidentPool, caesar_entry, carus_entry,
+                       instr_bucket, nop_entry, tile_bucket)
 from repro.nmc.engine import get_engine
 from repro.nmc.program import PROG_DTYPE
 
@@ -138,9 +136,10 @@ def test_ecpu_issue_trace_is_ir_program():
     assert isinstance(prog, Program) and prog.engine == "carus"
     assert prog.n_instr == cpu.vector_retired == 2
     # replay the full trace through the pool; must equal the eager result
-    pool = TilePool()
-    (final,) = pool.run([prog], [vrf])
-    assert (np.asarray(cpu.vrf) == final).all()
+    pool = ResidentPool()
+    pool.load("t", "carus", vrf)
+    pool.dispatch([("t", prog)])
+    assert (np.asarray(cpu.vrf) == np.asarray(pool.state("t"))).all()
 
 
 # ---------------------------------------------------------------------------
@@ -150,7 +149,7 @@ def test_ecpu_issue_trace_is_ir_program():
 @pytest.mark.parametrize("sew", [8, 16, 32])
 def test_pool_bit_exact_all_kernels(sew):
     kbs = [_build(name, sew) for name in programs.ALL_KERNELS]
-    pool = TilePool()
+    pool = ResidentPool()
     builds = [kb.caesar for kb in kbs] + [kb.carus for kb in kbs]
     batched = pool.run_builds(builds)
     for eb, got in zip(builds, batched):
@@ -162,36 +161,23 @@ def test_pool_bit_exact_all_kernels(sew):
         exp = np.asarray(eb.oracle).reshape(-1)
         assert (np.asarray(got).reshape(-1)[:exp.size] == exp).all(), \
             (eb.engine, sew)
-    # grouped dispatch: strictly fewer compiles than kernel instances
-    assert pool.compiles == len({eb.program.shape_key for eb in builds})
+    # grouped dispatch: one compile per bucket, strictly fewer compiles
+    # than kernel instances
+    assert pool.compiles == len({eb.program.bucket_key for eb in builds})
     assert pool.compiles < len(builds)
-
-
-def test_pool_compiles_once_per_shape():
-    """Same-shape instances share one compile; re-dispatch hits the cache."""
-    kbs = [_build(name, 8) for name in ("xor", "add", "mul")]
-    builds = [kb.caesar for kb in kbs]
-    keys = {eb.program.shape_key for eb in builds}
-    assert len(keys) == 1, keys       # one shape => batched as 3 tiles
-    pool = TilePool()
-    pool.run_builds(builds)
-    assert pool.compiles == 1
-    assert pool.dispatches == 1 and pool.programs_run == 3
-    pool.run_builds(builds)           # same shape again: no new compile
-    assert pool.compiles == 1
-    assert pool.shape_keys_compiled == keys
 
 
 def test_pool_groups_heterogeneous_batches():
     kbs = [_build("xor", 8), _build("relu", 8), _build("matmul", 8)]
-    pool = TilePool()
+    pool = ResidentPool()
     res = programs.verify_sweep(kbs, pool)
     assert all(all(v.values()) for v in res.values())
-    shapes = {getattr(kb, e).program.shape_key
-              for kb in kbs for e in ("caesar", "carus")}
-    assert pool.compiles == len(shapes)
-    # xor and relu lower to the same caesar shape => batched together
-    assert pool.programs_run == 6 and pool.dispatches == len(shapes)
+    buckets = {getattr(kb, e).program.bucket_key
+               for kb in kbs for e in ("caesar", "carus")}
+    assert pool.compiles == len(buckets)
+    # programs sharing a bucket (xor and relu on NM-Caesar) batch together
+    assert pool.programs_run == 6 and pool.dispatches == len(buckets)
+    assert pool.dispatches < pool.programs_run
 
 
 # ---------------------------------------------------------------------------
@@ -250,38 +236,49 @@ def _caesar_prog(n_instr: int, sew: int = 8) -> Program:
          for i in range(n_instr)])
 
 
+def _dispatch_fresh(pool: ResidentPool, progs: list, state) -> list:
+    """Load ``state`` onto one fresh tile per program, dispatch the wave,
+    and return the tile ids."""
+    tiles = [("t", next(pool._ids)) for _ in progs]
+    for t, p in zip(tiles, progs):
+        pool.load(t, p.engine, state)
+    pool.dispatch(list(zip(tiles, progs)))
+    return tiles
+
+
 def test_bucketed_pool_merges_ragged_shapes():
     """Four distinct exact shapes in one instr bucket: one compile, one
-    batched dispatch, bit-exact vs the exact-shape pool."""
+    batched dispatch, bit-exact vs each program run alone on the scan
+    engine."""
     progs = [_caesar_prog(n) for n in (5, 6, 7, 8)]
-    states = [np.arange(8192, dtype=np.int32) for _ in progs]
-    pool = BucketedPool()
-    outs = pool.run(progs, [s.copy() for s in states])
-    assert len({p.shape_key for p in progs}) == 4       # exact: 4 traces
+    state = np.arange(8192, dtype=np.int32)
+    pool = ResidentPool()
+    tiles = _dispatch_fresh(pool, progs, state)
+    assert len({p.shape_key for p in progs}) == 4       # exact: 4 shapes
     assert pool.compiles == 1                           # bucketed: 1
     assert pool.dispatches == 1 and pool.programs_run == 4
     # pad_waste: 4 tiles x bucket 8 - (5+6+7+8) real instructions
-    assert pool.pad_waste == 4 * 8 - (5 + 6 + 7 + 8)
-    assert pool.bytes_moved > 0
-    exact = TilePool()
-    refs = exact.run(progs, [s.copy() for s in states])
-    assert exact.compiles == 4
-    for got, ref in zip(outs, refs):
-        assert (got == ref).all()
+    assert pool.pool.pad_waste == 4 * 8 - (5 + 6 + 7 + 8)
+    # four image loads plus one packed 4 x 8-entry stream
+    assert pool.bytes_moved == 4 * 8192 * 4 + 4 * 8 * PROG_DTYPE.itemsize
+    eng = get_engine("caesar", "scan")
+    for t, p in zip(tiles, progs):
+        ref = np.asarray(eng.run(eng.init_state(state), p))
+        assert (np.asarray(pool.state(t)) == ref).all()
 
 
 def test_bucketed_pool_tile_count_buckets_reuse_traces():
     """Partial batches pad to power-of-two tile counts and reuse the
     padded-batch trace instead of re-tracing per count."""
-    pool = BucketedPool()
+    pool = ResidentPool()
     state = np.zeros(8192, np.int32)
-    pool.run([_caesar_prog(8)] * 3, [state] * 3)   # 3 tiles -> bucket 4
+    _dispatch_fresh(pool, [_caesar_prog(8)] * 3, state)  # 3 -> bucket 4
     assert pool.compiles == 1
-    pool.run([_caesar_prog(8)] * 4, [state] * 4)   # 4 tiles -> same bucket
+    _dispatch_fresh(pool, [_caesar_prog(8)] * 4, state)  # 4 -> same bucket
     assert pool.compiles == 1
-    pool.run([_caesar_prog(6)] * 4, [state] * 4)   # same buckets again
+    _dispatch_fresh(pool, [_caesar_prog(6)] * 4, state)  # same buckets
     assert pool.compiles == 1
-    pool.run([_caesar_prog(8)] * 2, [state] * 2)   # 2 tiles -> new bucket
+    _dispatch_fresh(pool, [_caesar_prog(8)] * 2, state)  # 2 -> new bucket
     assert pool.compiles == 2
 
 
@@ -292,7 +289,7 @@ def test_bucketed_pool_table_v_sweep(sew):
     most once per (engine, sew, bucket) — asserted on the pool counters."""
     kbs = [_build(name, sew) for name in programs.ALL_KERNELS]
     builds = [getattr(kb, e) for kb in kbs for e in ("caesar", "carus")]
-    pool = BucketedPool()
+    pool = ResidentPool()
     outs = pool.run_builds(builds)
     for eb, got in zip(builds, outs):
         # bit-exact vs the per-engine oracles (the single-program path is
@@ -362,11 +359,13 @@ def test_resident_pool_byte_accounting_and_outputs():
 
 
 def test_resident_run_builds_matches_pool_run_builds():
+    """The batched resident path equals each build run alone on its
+    engine (the single-instance ``run_build`` path)."""
     kbs = [_build(n, 8) for n in ("xor", "add", "relu")]
     builds = [getattr(kb, e) for kb in kbs for e in ("caesar", "carus")]
     rp = ResidentPool()
     got = rp.run_builds(builds)
-    ref = BucketedPool().run_builds(builds)
+    ref = [programs.run_build(eb) for eb in builds]
     for a, b in zip(got, ref):
         assert (np.asarray(a) == np.asarray(b)).all()
     assert rp.loads == len(builds) and rp.stores == len(builds)

@@ -27,6 +27,7 @@ ALL_SEWS = (8, 16, 32)
 RNG = np.random.default_rng(11)
 
 _RT = nmc.NmcRuntime()
+_RT_BY_BACKEND = {bk: nmc.NmcRuntime(backend=bk) for bk in nmc.BACKENDS}
 
 
 def _rand(n, sew):
@@ -94,9 +95,10 @@ def test_opt_bit_exact_both_backends(engine, backend, sew):
     stack on both engines x both executors x every SEW (the acceptance
     matrix)."""
     args = _axpy_args(sew)
-    k = nmc.jit(axpy, engine=engine, sew=sew, runtime=_RT)
-    got_off = np.asarray(k(*args, opt="off", backend=backend))
-    got_o1 = np.asarray(k(*args, backend=backend))
+    k = nmc.jit(axpy, engine=engine, sew=sew,
+                runtime=_RT_BY_BACKEND[backend])
+    got_off = np.asarray(k(*args, opt="off"))
+    got_o1 = np.asarray(k(*args))
     assert np.array_equal(got_off, got_o1)
     assert np.array_equal(got_o1, k.oracle(*args))
 

@@ -2,14 +2,14 @@
 
 Covers the ISSUE-3 acceptance criteria:
 
-* hand-computed counter invariants for :class:`BucketedPool`
-  (``compiles`` / ``pad_waste`` / ``bytes_moved``) and
-  :class:`ResidentPool` (load/dispatch/store byte accounting) over
-  mixed-shape sweeps — exact equalities, not bounds;
+* hand-computed counter invariants for :class:`ResidentPool`
+  (``compiles`` / ``pad_waste`` / load/dispatch/store byte accounting)
+  over mixed-shape sweeps — exact equalities, not bounds;
+* every layer of the stack defaults to the executor the platform picks;
 * :class:`DispatchQueue` futures resolve **bit-exact equal** to synchronous
   ``ResidentPool`` dispatch on the full Table V sweep (all kernels x both
-  engines x SEW in {8, 16, 32}), under both schedulers, including
-  round-robin tile reuse (double buffering) and chained per-tile programs;
+  engines x SEW in {8, 16, 32}), including round-robin tile reuse (double
+  buffering) and chained per-tile programs;
 * the overlapped-DMA timing mode reports <= the serial mode's cycles on
   every kernel sweep, strictly less on the matmul sweep, with the pipeline
   makespan hand-computed on synthetic stages.
@@ -34,7 +34,7 @@ ALL_SEWS = (8, 16, 32)
 # one bucketed jit cache for the whole module: sync pools and queues share
 # traces (the compile-once property the scheduler tests already prove), so
 # the full-sweep differential below costs execution time, not compile time
-_SHARED = BucketedPool(donate=True)
+_SHARED = BucketedPool()
 
 
 @functools.lru_cache(maxsize=None)
@@ -66,23 +66,29 @@ def _carus_prog(n_instr: int, sew: int = 8) -> Program:
 
 def test_bucketed_pool_counters_hand_computed():
     """Mixed-shape sweep: three caesar programs in the 8-bucket (3 tiles ->
-    tile-bucket 4), one caesar and one carus program in the 4-bucket.
-    Every counter is checked against the by-hand arithmetic."""
+    tile-bucket 4), one caesar and one carus program in the 4-bucket,
+    loaded, dispatched and stored through a resident pool.  Every counter
+    is checked against the by-hand arithmetic."""
     progs = [_caesar_prog(5), _caesar_prog(6), _caesar_prog(7),
              _caesar_prog(3), _carus_prog(3)]
-    states = [np.zeros(8192, np.int32)] * 4 + [np.zeros((32, 256), np.int32)]
-    pool = BucketedPool()
-    pool.run(progs, states)
-    assert pool.compiles == 3            # (c,8,8)x4t, (c,8,4)x1t, (k,8,4)x1t
-    assert pool.dispatches == 3 and pool.programs_run == 5
+    rp = ResidentPool(pool=BucketedPool())
+    for t, p in enumerate(progs):
+        rp.load(t, p.engine, np.zeros(_STATE_SHAPE[p.engine], np.int32))
+    rp.dispatch(list(enumerate(progs)))
+    for t in range(len(progs)):
+        rp.store(t, (100, 3), 8)
+    assert rp.compiles == 3              # (c,8,8)x4t, (c,8,4)x1t, (k,8,4)x1t
+    assert rp.dispatches == 3 and rp.programs_run == 5
     # pad_waste: [4 tiles x bucket 8 - (5+6+7)] + [4 - 3] + [4 - 3]
-    assert pool.pad_waste == (4 * 8 - 18) + 1 + 1 == 16
+    assert rp.pool.pad_waste == (4 * 8 - 18) + 1 + 1 == 16
+    assert rp.pool.useful_instrs == 5 + 6 + 7 + 3 + 3
     e = PROG_DTYPE.itemsize              # 8 int32 fields = 32 B per entry
     assert e == 32
     state_b = 8192 * 4                   # every image is 8192 words
-    expected = ((4 * 8 * e + 4 * state_b + 4 * state_b)    # 8-bucket group
-                + 2 * (1 * 4 * e + state_b + state_b))     # two 4-buckets
-    assert pool.bytes_moved == expected == 394496
+    expected = (5 * state_b                              # loads
+                + 4 * 8 * e + 2 * (1 * 4 * e)            # streams
+                + 5 * 3 * 4)                             # 3-word stores
+    assert rp.bytes_moved == expected == 165180
 
 
 def test_resident_pool_mixed_engine_accounting():
@@ -125,16 +131,16 @@ def _prog(engine: str, n_instr: int, sew: int = 8) -> Program:
 
 @pytest.mark.parametrize("engine", ("caesar", "carus"))
 def test_pack_programs_matches_stack_programs(engine):
-    """The packed ``[F, T, n]`` stream holds exactly the per-field stacks,
-    in ``field_map()`` order."""
-    from repro.nmc.program import pack_programs, stack_programs
+    """The packed ``[F, T, n]`` stream holds exactly each program's lowered
+    engine fields, in ``field_map()`` order."""
+    from repro.nmc.program import pack_programs
     progs = [_prog(engine, 8).pad_to(8), _prog(engine, 5).pad_to(8)]
     packed = pack_programs(progs)
-    stacked = stack_programs(progs)
     assert packed.dtype == np.int32 and packed.flags.c_contiguous
     assert packed.shape == (len(progs[0].field_map()), 2, 8)
     for i, (field, _) in enumerate(progs[0].field_map()):
-        assert np.array_equal(packed[i], stacked[field])
+        for t, p in enumerate(progs):
+            assert np.array_equal(packed[i, t], p.lower_np()[field])
 
 
 @pytest.mark.parametrize("via", ("dispatch", "queue"))
@@ -143,7 +149,7 @@ def test_resident_dispatch_one_upload_per_bucket_group(via):
     (bucket 8, 3 tiles -> tile bucket 4), caesar 3 (bucket 4, 1 tile) and
     carus 3/4 (bucket 4, 2 tiles) — makes one stream upload per group and
     leaves every other counter at its hand-computed value."""
-    rp = ResidentPool(pool=BucketedPool(donate=True))
+    rp = ResidentPool(pool=BucketedPool())
     wave = [(("c", i), _caesar_prog(n)) for i, n in enumerate((5, 6, 7, 3))]
     wave += [(("k", i), _carus_prog(n)) for i, n in enumerate((3, 4))]
     images = {t: np.zeros(_STATE_SHAPE[p.engine], np.int32) for t, p in wave}
@@ -223,7 +229,7 @@ def test_group_calls_compile_once_across_passes(n_tiles):
     pass left them (states staged from images and states handed on from
     earlier calls share one trace)."""
     builds = _small_builds()
-    pool = BucketedPool(donate=True)
+    pool = BucketedPool()
     queue = DispatchQueue(pool=ResidentPool(pool=pool))
     first = queue.run_builds(builds, n_tiles=n_tiles)
     compiles = pool.compiles
@@ -263,19 +269,16 @@ def test_async_queue_bit_exact_full_table_v(sew):
 
 
 def test_inorder_and_overlapped_schedulers_agree():
+    """The queue on a 2-tile array equals synchronous dispatch bit for
+    bit, staging every image after the first wave while its tile is
+    busy."""
     builds = _small_builds()
     ref = ResidentPool(pool=_SHARED).run_builds(builds)
     qo = DispatchQueue(pool=ResidentPool(pool=_SHARED))
-    qi = DispatchQueue(pool=ResidentPool(pool=_SHARED), mode="inorder")
     oo = qo.run_builds(builds, n_tiles=2)
-    oi = qi.run_builds(builds, n_tiles=2)
-    for a, b, c in zip(ref, oo, oi):
+    for a, b in zip(ref, oo):
         assert (np.asarray(a) == np.asarray(b)).all()
-        assert (np.asarray(a) == np.asarray(c)).all()
-    # overlapped stages eagerly while tiles are busy; inorder never does
     assert qo.staged_while_busy == len(builds) - 2
-    assert qi.staged_while_busy == 0
-    assert qi.waves == len(builds)       # one single-item wave per submit
     assert qo.waves == len(builds) // 2
 
 
@@ -317,19 +320,6 @@ def test_drain_resolves_chained_futures():
     assert queue.pool.stores == 2        # both results were extracted
 
 
-def test_run_builds_queue_threading_and_pool_guard():
-    builds = _small_builds()[:4]
-    rp = ResidentPool(pool=_SHARED)
-    queue = DispatchQueue(pool=rp)
-    got = rp.run_builds(builds, queue=queue)
-    ref = ResidentPool(pool=_SHARED).run_builds(builds)
-    for a, b in zip(ref, got):
-        assert (np.asarray(a) == np.asarray(b)).all()
-    # a queue wrapping a different pool must be rejected
-    with pytest.raises(AssertionError):
-        ResidentPool(pool=_SHARED).run_builds(builds, queue=queue)
-
-
 def test_queue_tile_ids_never_collide_with_pool_run_builds():
     """Anonymous queue tiles draw from the pool's id counter, so mixing
     sync and async run_builds on one pool never clobbers resident state."""
@@ -341,12 +331,20 @@ def test_queue_tile_ids_never_collide_with_pool_run_builds():
     assert len(rp.tiles) == n_sync + len(builds)   # all tiles distinct
 
 
-def test_submit_call_device_future():
-    import jax.numpy as jnp
-    queue = DispatchQueue(pool=ResidentPool(pool=_SHARED))
-    fut = queue.submit_call(lambda a, b: a @ b, jnp.eye(4), jnp.arange(4.0))
-    assert queue.calls == 1
-    assert np.allclose(np.asarray(fut.result()), np.arange(4.0))
+@pytest.mark.parametrize("make", ("BucketedPool", "ResidentPool",
+                                  "DispatchQueue", "NmcRuntime"))
+def test_default_executor_follows_platform(monkeypatch, make):
+    """Built with no arguments, every layer of the stack runs on the
+    executor the platform picks: Pallas on a TPU (nothing runs here)."""
+    import jax
+    from repro import nmc
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    top = getattr(nmc, make)()
+    pool = {"BucketedPool": lambda: top,
+            "ResidentPool": lambda: top.pool,
+            "DispatchQueue": lambda: top.pool.pool,
+            "NmcRuntime": lambda: top.bucketed}[make]()
+    assert pool.backend == "pallas"
 
 
 # ---------------------------------------------------------------------------
@@ -412,10 +410,11 @@ def test_wave_speedup_saturates_when_bus_binds():
 
 
 def test_store_accounting_word_granular_for_subword_tails():
-    """ResidentPool.store / DispatchQueue._account_store count whole bus
-    words: a sub-word element tail (gathered shards at SEW 8/16 make odd
-    tails common) still moves its full last word.  Locks the audited
-    behavior of the 32-bit-bus accounting model."""
+    """ResidentPool.store and a resolving future (both through
+    ResidentPool.extract) count whole bus words: a sub-word element tail
+    (gathered shards at SEW 8/16 make odd tails common) still moves its
+    full last word.  Locks the audited behavior of the 32-bit-bus
+    accounting model."""
     rp = ResidentPool(pool=_SHARED)
     rp.load(("acct", 0), "caesar", np.zeros(8192, np.int32))
     b0 = rp.bytes_moved

@@ -289,9 +289,9 @@ def test_max_prefills_bounds_admission():
 
 
 def test_dispatch_queue_counters_mixed_traffic():
-    """PR 8 coverage: DispatchQueue counter invariants under mixed
-    submit (tile programs) and submit_call (generic device work) traffic
-    through one private queue."""
+    """DispatchQueue counter invariants for tile-program traffic through
+    one private queue, with serving traffic on the same engine beside
+    it."""
     from repro import nmc
     cfg = cb.get("qwen1.5-0.5b", smoke=True).scaled(nmc_mode="w8a8")
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
@@ -309,13 +309,38 @@ def test_dispatch_queue_counters_mixed_traffic():
     eng.submit(Request(
         rid=0, prompt=rng.integers(0, cfg.vocab_size, 6).astype(np.int32),
         max_new=3))
-    eng.run()                                       # submit_call traffic
+    eng.run()                                       # serving traffic
     own.drain()
     # lifecycle conservation: everything submitted launched and resolved
     assert own.submitted == own.launched == own.resolved == 2
     assert own.waves >= 1
-    # generic device work is counted separately: 1 prefill + decode steps
-    assert own.calls >= 3
     # a second projection through the same queue keeps the books balanced
     eng.nmc_project(x8, w8)
     assert own.submitted == own.launched == own.resolved == 4
+
+
+def test_serving_runs_its_programs_without_the_nmc_queue():
+    """Prefill and decode are called directly: an engine whose NMC queue
+    refuses every submit serves requests to completion, with the tokens
+    of an engine on the default queue."""
+    from repro import nmc
+
+    class RefusingQueue(nmc.DispatchQueue):
+        def submit(self, *args, **kwargs):
+            raise AssertionError("serving submitted to the NMC queue")
+
+    cfg = cb.get("h2o-danube-1.8b", smoke=True).scaled(dtype=jnp.float32)
+    params = lm.init_params(jax.random.PRNGKey(3), cfg)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 9, 7)]
+    outs = []
+    for queue in (RefusingQueue(), None):
+        eng = ServeEngine(cfg, params, n_slots=2, max_len=64,
+                          nmc_queue=queue)
+        for i, p in enumerate(prompts):
+            eng.submit(Request(rid=i, prompt=p, max_new=4))
+        done = sorted(eng.run(), key=lambda r: r.rid)
+        assert [len(r.out) for r in done] == [4, 4, 4]
+        outs.append([r.out for r in done])
+    assert outs[0] == outs[1]

@@ -134,7 +134,7 @@ def test_resident_group_call_compiles(one_chip, monkeypatch, engine):
     monkeypatch.setattr(nmc_engine, "_DEFAULT_ENGINES",
                         {(engine, "pallas"): eng})
     tiles, bucket = 16, 1024
-    fn = BucketedPool(donate=True, backend="pallas")._group_fn(
+    fn = BucketedPool(backend="pallas")._group_fn(
         (engine, 8, bucket), tiles)
     shape = (8192,) if engine == "caesar" else (32, 256)
     n_fields = len(pe.CAESAR_FIELDS if engine == "caesar"
